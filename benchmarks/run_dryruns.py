@@ -44,6 +44,8 @@ def record_path(arch: str, shape: str, mesh: str) -> str:
 def run_one(arch: str, shape: str, mesh: str, q: int, timeout: int = 3600) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # the dry run forces 512 host devices: a CPU rehearsal by design
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [
         sys.executable, "-m", "repro.launch.dryrun",
         "--arch", arch, "--shape", shape, "--mesh", mesh,

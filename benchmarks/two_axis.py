@@ -43,6 +43,9 @@ def run_cell(nodes: int, shards: int, *, total: int = 8192,
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(REPO, "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
+    # forced host devices are a CPU rehearsal: the child stays off any
+    # accelerator (the parent may already hold it)
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.two_axis",
            "--nodes", str(nodes), "--shards", str(shards),
            "--total", str(total), "--chunk", str(chunk),
@@ -136,7 +139,7 @@ def _child_main() -> None:
     mesh = jax.make_mesh((n, s), ("data", "model"))
     engine = ShardedFusedEngine.from_mesh(
         mesh, ("data",), params, scale_chunk=args.chunk, topk=args.topk,
-        impl="jnp", model_axis="model" if s > 1 else None)
+        impl="pallas", model_axis="model" if s > 1 else None)
     cfg = FLConfig(algorithm=args.algorithm, q=args.q, n_nodes=n)
     flat, _ = pack(params, pad_to=args.chunk * s)
     with mesh:

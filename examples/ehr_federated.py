@@ -49,6 +49,7 @@ from repro.core.dynamics import program_names
 from repro.core.engine import schedule_names
 from repro.core.schedules import inv_sqrt
 from repro.data.ehr import generate_ehr_cohort, make_node_batcher
+from repro.launch.cache import enable_compile_cache
 from repro.models.mlp import (
     make_mlp_loss,
     mlp_accuracy,
@@ -268,6 +269,7 @@ def main() -> None:
                          "saturation) or 'none' for the paper-faithful "
                          "unweighted loss")
     args = ap.parse_args()
+    enable_compile_cache()
 
     results = run(iterations=args.iterations)
 

@@ -12,9 +12,12 @@ import numpy as np
 
 from repro.configs import FLRunConfig, get_config
 from repro.data.tokens import make_fl_token_batches
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServeEngine
 from repro.training.trainer import train_decentralized
+
+enable_compile_cache()
 
 # 1. pick an architecture (any of the 10 assigned ids works)
 cfg = get_config("tinyllama-1.1b", smoke=True)

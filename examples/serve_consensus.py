@@ -47,6 +47,7 @@ from repro.core import (  # noqa: E402
 )
 from repro.core.schedules import inv_sqrt  # noqa: E402
 from repro.data.tokens import make_fl_token_batches  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.serving.engine import ServeEngine  # noqa: E402
 from repro.training.snapshot import (  # noqa: E402
@@ -77,6 +78,7 @@ def main() -> None:
                     help="snapshot directory (default: a temp dir)")
     ap.add_argument("--out", default="experiments/serve_consensus_metrics.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=True)
     bundle = build_model(cfg)

@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServeEngine
 
@@ -36,6 +37,7 @@ def demo(arch: str, sliding: bool = False, batch: int = 2, max_new: int = 12) ->
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("batched decode across model families (reduced configs, CPU):")
     demo("tinyllama-1.1b")                 # dense GQA, contiguous KV cache
     demo("qwen2.5-32b", sliding=True)      # dense, ring-buffer window cache

@@ -8,8 +8,9 @@ Two modes:
     through the simulated tree engine (single device, dense-W gossip);
 
   * decentralized -- ``--fl-engine sharded_fused`` builds the round on a
-    real ``(gossip_node, model_shard)`` device mesh (forced host devices
-    off-TPU): each node's parameters live as one flat buffer whose
+    ``(gossip_node, model_shard)`` mesh of forced host CPU devices (a
+    rehearsal of the chip path; ``chip_smoke.py --chips 4`` runs it on
+    TPUs): each node's parameters live as one flat buffer whose
     columns tile over the model axis, the wire stage runs one fused pass
     per (node, shard) tile, and the int8 gossip collective stays on the
     node axis only. ``--arch smollm-360m`` swaps in the SmolLM-360M
@@ -39,6 +40,8 @@ def _argv_value(flag, default):
 if _argv_value("--fl-engine", "tree") == "sharded_fused":
     _n = int(_argv_value("--nodes", "4"))
     _s = int(_argv_value("--model-shards", "1"))
+    # forced host devices are a CPU rehearsal: stay off any accelerator
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={_n * _s} "
         + os.environ.get("XLA_FLAGS", "")
@@ -53,6 +56,7 @@ import jax  # noqa: E402
 from repro.configs import FLRunConfig, get_config  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
 from repro.data.tokens import make_fl_token_batches  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.training.checkpoint import save_fl_state  # noqa: E402
 from repro.training.trainer import (  # noqa: E402
@@ -85,7 +89,7 @@ def build_sharded_engine(args, stacked):
     mesh = jax.make_mesh((args.nodes, shards), ("data", "model"))
     engine = ShardedFusedEngine.from_mesh(
         mesh, ("data",), stacked, scale_chunk=args.scale_chunk,
-        topk=args.topk, impl="jnp",
+        topk=args.topk, impl="pallas",
         model_axis="model" if shards > 1 else None,
         round_schedule=args.fl_schedule,
         topology_program=args.fl_topology_program,
@@ -133,6 +137,7 @@ def main() -> None:
                     help="wire privacy epilogue, e.g. "
                          "'secure_agg+dp:sigma=0.5,clip=1.0'")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "llama-100m":
         if args.smoke:

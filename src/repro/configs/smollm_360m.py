@@ -1,5 +1,5 @@
 """SmolLM-360M [dense] — 32L d_model=960 15H (GQA kv=5) d_ff=2560
-vocab=49152 — llama-arch small [hf:HuggingFaceTB/SmolLM-135M]."""
+vocab=49152 — llama-arch small [hf:HuggingFaceTB/SmolLM-360M]."""
 
 from repro.configs.base import ModelConfig
 
@@ -15,7 +15,7 @@ CONFIG = ModelConfig(
     head_dim=64,
     window=4096,
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )
 
 
@@ -32,5 +32,5 @@ def smoke_config() -> ModelConfig:
         head_dim=64,
         window=64,
         tie_embeddings=True,
-        source="hf:HuggingFaceTB/SmolLM-135M",
+        source="hf:HuggingFaceTB/SmolLM-360M",
     )

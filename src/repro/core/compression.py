@@ -142,6 +142,9 @@ def make_compressed_flat_gossip(
         from repro.kernels.gossip.ref import gossip_mix_ref as mix_impl
     elif impl == "pallas":
         from repro.kernels.gossip.ops import gossip_mix as mix_impl
+        from repro.kernels.gossip.ops import require_topk_lowering
+
+        require_topk_lowering(topk)
     else:
         raise ValueError(f"unknown impl {impl!r}")
     w = np.asarray(w, dtype=np.float64)

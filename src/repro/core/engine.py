@@ -600,8 +600,11 @@ class GossipEngine(abc.ABC):
         round W is a traced OPERAND of the one compiled round, the
         counter/state advance rides in the returned comm entries, and
         the metrics report the realized edge/payload fractions."""
-        r = comm["topo_round"]
-        new_comm: Dict[str, jnp.ndarray] = {"topo_round": r + 1}
+        # a static round without privacy or scope keeps no counter; its
+        # gates are the fixed W
+        r = comm.get("topo_round")
+        new_comm: Dict[str, jnp.ndarray] = (
+            {} if r is None else {"topo_round": r + 1})
         metrics: Dict[str, jnp.ndarray] = {}
         topo = self.topology_program
         if self.dynamic_topology:
@@ -1281,6 +1284,10 @@ class _FusedBase(GossipEngine):
                  node_program=None, privacy=None, scope=None):
         if impl not in ("pallas", "jnp"):
             raise ValueError(f"unknown impl {impl!r}")
+        if impl == "pallas":
+            from repro.kernels.gossip.ops import require_topk_lowering
+
+            require_topk_lowering(topk)
         if scale_chunk < 1:
             raise ValueError("scale_chunk must be >= 1")
         if topk is not None and not (1 <= topk):
@@ -1968,15 +1975,17 @@ class FusedEngine(_FusedBase):
     @classmethod
     def from_mesh(cls, mesh: Mesh, node_axes: Sequence[str], stacked_sds,
                   *, wire_dtype=None, axes_subset=None, scale_chunk: int = 512,
-                  topk=None, impl: str = "jnp", error_feedback: bool = True,
+                  topk=None, impl: str = "pallas",
+                  error_feedback: bool = True,
                   difference_coding: bool = True, self_weight=None,
                   round_schedule=None, storage_dtype=None,
                   topology_program=None, node_program=None, privacy=None,
                   scope=None, **_ignored):
         """Mesh build: W is the dense equivalent of the circulant torus the
         ppermute backend realizes over the node axes (directions restricted
-        to ``axes_subset`` for hierarchical gossip). ``impl`` defaults to
-        the jnp oracle, which GSPMD partitions in lowering-only dry runs."""
+        to ``axes_subset`` for hierarchical gossip). The lowering-only dry
+        run passes ``impl="jnp"``: GSPMD partitions the jnp oracle, not a
+        Pallas call."""
         _reject_wire_dtype(wire_dtype)
         w = mesh_gossip_dense_equivalent(
             {a: mesh.shape[a] for a in node_axes}, self_weight=self_weight,

@@ -50,6 +50,7 @@ full-precision wire.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -59,15 +60,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.packing import pack, unpack
 
-try:  # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: replication inference cannot see through
-    # the pack (concat/slice) ops, so disable the static check there
-    from functools import partial as _partial
-
-    from jax.experimental.shard_map import shard_map as _sm_impl
-
-    _shard_map = _partial(_sm_impl, check_rep=False)
+# Replication of the shard_map outputs cannot be inferred through the
+# pack (concat/slice) ops and the hand-rolled ppermute mixes, so the
+# static varying-manual-axes check is off; every out_spec names the node
+# axes it varies over explicitly.
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 PyTree = Any
 GossipFn = Callable[[PyTree], PyTree]

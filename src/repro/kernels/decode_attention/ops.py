@@ -3,21 +3,14 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.decode_attention.decode_attention import decode_attention_bhd
 
 __all__ = ["decode_attention"]
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_c",))
@@ -37,6 +30,7 @@ def decode_attention(
     vf = v_cache.transpose(0, 2, 1, 3).reshape(b * n_kv, c, hd)
     out = decode_attention_bhd(
         qf, kf, vf, n_valid.astype(jnp.int32),
-        n_q_heads=h, n_kv_heads=n_kv, block_c=block_c, interpret=_interpret(),
+        n_q_heads=h, n_kv_heads=n_kv, block_c=block_c,
+        interpret=pallas_interpret(),
     )
     return out.reshape(b, h, 1, hd).transpose(0, 2, 1, 3)

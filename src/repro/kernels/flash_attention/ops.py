@@ -2,28 +2,20 @@
 
 Models call with (B, S, H, hd) activations; this wrapper folds to the
 kernel's (B*H, S, hd) layout, picks MXU-aligned block sizes, and selects
-interpret mode automatically off-TPU (kernel-body-in-Python validation, the
-only execution mode available in this CPU container).
+interpret mode off-TPU through :func:`repro.kernels.pallas_interpret`.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
 
 __all__ = ["flash_attention"]
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
@@ -53,6 +45,6 @@ def flash_attention(
         n_kv_heads=n_kv,
         block_q=block_q,
         block_k=block_k,
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )
     return out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)

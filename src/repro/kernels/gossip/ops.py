@@ -1,20 +1,19 @@
 """jit'd dispatch for the fused gossip / round megakernels.
 
-Every entry point resolves Pallas ``interpret`` mode OUTSIDE the jit so
-the ``REPRO_PALLAS_INTERPRET`` environment variable is honored per call
-(not frozen into the first compilation): interpret defaults to on
-everywhere except a real TPU backend.
+Every entry point resolves Pallas ``interpret`` mode OUTSIDE the jit
+through :func:`repro.kernels.pallas_interpret` (never on a TPU backend,
+the default everywhere else).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.gossip.gossip import (
     fused_round_gt_pallas,
     fused_round_pallas,
@@ -26,14 +25,25 @@ from repro.kernels.gossip.gossip import (
 )
 
 __all__ = ["gossip_mix", "fused_round", "fused_round_gt", "wire_stage",
-           "wire_stage_gt", "wire_stage_compact", "wire_stage_gt_compact"]
+           "wire_stage_gt", "wire_stage_compact", "wire_stage_gt_compact",
+           "require_topk_lowering"]
 
 
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def require_topk_lowering(topk) -> None:
+    """Refuse a top-k wire where the kernels compile for the chip.
+
+    The top-k selections (``jnp.sort`` for the masked wire,
+    ``jax.lax.top_k`` for the compact one) have no Mosaic lowering, so
+    on a TPU the sparsified kernels cannot be built. There is no dense
+    or jnp substitute: the caller drops ``topk``, or runs the jnp oracle
+    (``impl="jnp"``) knowingly.
+    """
+    if topk is not None and not pallas_interpret():
+        raise NotImplementedError(
+            f"topk={topk}: the Pallas top-k wire does not compile for the "
+            "chip (Mosaic has no lowering for sort / top_k); drop topk for "
+            "the dense int8 wire"
+        )
 
 
 def _dp_substitute(h, base, res, dp_clip, dp_noise):
@@ -155,7 +165,7 @@ def gossip_mix(
     """
     return _gossip_mix(
         x, recon, res, w_off, w_self, scale_chunk, error_feedback,
-        difference_coding, topk, stale_mix, _interpret(),
+        difference_coding, topk, stale_mix, pallas_interpret(),
     )
 
 
@@ -214,7 +224,8 @@ def fused_round(
     if dp_noise is None:
         return _fused_round(
             x, g, recon, res, w_off, w_self, alpha, scale_chunk,
-            error_feedback, difference_coding, topk, stale_mix, _interpret(),
+            error_feedback, difference_coding, topk, stale_mix,
+            pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     h = x - alpha * g
@@ -222,7 +233,8 @@ def fused_round(
     res_sub, corr = _dp_substitute(h, base, res, dp_clip, dp_noise)
     mixed, new_recon, new_res, scales = _fused_round(
         x, g, recon, res_sub, w_off, w_self, alpha, scale_chunk,
-        error_feedback, difference_coding, topk, stale_mix, _interpret(),
+        error_feedback, difference_coding, topk, stale_mix,
+        pallas_interpret(),
     )
     return mixed, new_recon, new_res + corr, scales
 
@@ -294,7 +306,7 @@ def fused_round_gt(
         return _fused_round_gt(
             x, t, g, g_prev, recon_x, res_x, recon_t, res_t, w_off, w_self,
             alpha, scale_chunk, error_feedback, difference_coding, topk,
-            stale_mix, _interpret(),
+            stale_mix, pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     t_half = t + g - g_prev
@@ -308,7 +320,7 @@ def fused_round_gt(
     mx, mt, nrx, nsx, nrt, nst, scx, sct = _fused_round_gt(
         x, t, g, g_prev, recon_x, res_x_sub, recon_t, res_t_sub, w_off,
         w_self, alpha, scale_chunk, error_feedback, difference_coding, topk,
-        stale_mix, _interpret(),
+        stale_mix, pallas_interpret(),
     )
     return mx, mt, nrx, nsx + corr_x, nrt, nst + corr_t, scx, sct
 
@@ -349,7 +361,7 @@ def wire_stage(
     if dp_noise is None:
         return _wire_stage(
             x, g, recon, res, alpha, scale_chunk, error_feedback,
-            difference_coding, topk, _interpret(),
+            difference_coding, topk, pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     h = x - alpha * g
@@ -357,7 +369,7 @@ def wire_stage(
     res_sub, corr = _dp_substitute(h, base, res, dp_clip, dp_noise)
     h_out, q, scales, new_recon, new_res = _wire_stage(
         x, g, recon, res_sub, alpha, scale_chunk, error_feedback,
-        difference_coding, topk, _interpret(),
+        difference_coding, topk, pallas_interpret(),
     )
     return h_out, q, scales, new_recon, new_res + corr
 
@@ -404,7 +416,7 @@ def wire_stage_gt(
         return _wire_stage_gt(
             x, t, g, g_prev, recon_x, res_x, recon_t, res_t, alpha,
             scale_chunk, error_feedback, difference_coding, topk,
-            _interpret(),
+            pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     t_half = t + g - g_prev
@@ -417,7 +429,8 @@ def wire_stage_gt(
     )
     (h_out, th, qx, scx, nrx, nsx, qt, sct, nrt, nst) = _wire_stage_gt(
         x, t, g, g_prev, recon_x, res_x_sub, recon_t, res_t_sub, alpha,
-        scale_chunk, error_feedback, difference_coding, topk, _interpret(),
+        scale_chunk, error_feedback, difference_coding, topk,
+        pallas_interpret(),
     )
     return h_out, th, qx, scx, nrx, nsx + corr_x, qt, sct, nrt, nst + corr_t
 
@@ -464,7 +477,7 @@ def wire_stage_compact(
     if dp_noise is None:
         return _wire_stage_compact(
             x, g, recon, res, alpha, scale_chunk, error_feedback,
-            difference_coding, topk, bitmap, _interpret(),
+            difference_coding, topk, bitmap, pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     h = x - alpha * g
@@ -472,7 +485,7 @@ def wire_stage_compact(
     res_sub, corr = _dp_substitute(h, base, res, dp_clip, dp_noise)
     h_out, q, pos, scales, new_recon, new_res = _wire_stage_compact(
         x, g, recon, res_sub, alpha, scale_chunk, error_feedback,
-        difference_coding, topk, bitmap, _interpret(),
+        difference_coding, topk, bitmap, pallas_interpret(),
     )
     return h_out, q, pos, scales, new_recon, new_res + corr
 
@@ -522,7 +535,7 @@ def wire_stage_gt_compact(
         return _wire_stage_gt_compact(
             x, t, g, g_prev, recon_x, res_x, recon_t, res_t, alpha,
             scale_chunk, error_feedback, difference_coding, topk, bitmap,
-            _interpret(),
+            pallas_interpret(),
         )
     _require_ef_for_dp(error_feedback)
     t_half = t + g - g_prev
@@ -537,7 +550,7 @@ def wire_stage_gt_compact(
      qt, pt, sct, nrt, nst) = _wire_stage_gt_compact(
         x, t, g, g_prev, recon_x, res_x_sub, recon_t, res_t_sub, alpha,
         scale_chunk, error_feedback, difference_coding, topk, bitmap,
-        _interpret(),
+        pallas_interpret(),
     )
     return (h_out, th, qx, px, scx, nrx, nsx + corr_x,
             qt, pt, sct, nrt, nst + corr_t)

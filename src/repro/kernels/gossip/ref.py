@@ -276,7 +276,9 @@ def gossip_mix_ref(
     new_recon = base + dq
     new_res = payload - dq if error_feedback else res
     nbr = recon if stale_mix else new_recon
-    mixed = w_off @ nbr + w_self[:, None] * x
+    mixed = jnp.dot(w_off, nbr, precision=jax.lax.Precision.HIGHEST) + (
+        w_self[:, None] * x
+    )
     return mixed, new_recon, new_res, scales
 
 

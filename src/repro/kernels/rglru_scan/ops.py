@@ -3,22 +3,15 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.rglru_scan.rglru_scan import rglru_scan_pallas
 
 __all__ = ["rglru_scan"]
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk"))
@@ -34,5 +27,5 @@ def rglru_scan(
     ck = chunk if s % chunk == 0 else 1
     bd = block_d if w % block_d == 0 else w
     return rglru_scan_pallas(
-        log_a, b, h0, block_d=bd, chunk=ck, interpret=_interpret()
+        log_a, b, h0, block_d=bd, chunk=ck, interpret=pallas_interpret()
     )

@@ -3,22 +3,15 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.rwkv6_scan.rwkv6_scan import wkv6_chunked_pallas
 
 __all__ = ["wkv6"]
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -41,7 +34,8 @@ def wkv6(
     ss = s0.reshape(b * h, hd, hd).astype(jnp.float32)
     ck = chunk if s % chunk == 0 else 1
     y, s_fin = wkv6_chunked_pallas(
-        fold(r), fold(k), fold(v), fold(log_w), uu, ss, chunk=ck, interpret=_interpret()
+        fold(r), fold(k), fold(v), fold(log_w), uu, ss, chunk=ck,
+        interpret=pallas_interpret(),
     )
     return (
         y.reshape(b, h, s, hd).transpose(0, 2, 1, 3),

@@ -48,7 +48,6 @@ from repro.core.fl import FLConfig, FLState, make_fl_round  # noqa: E402
 from repro.core.schedules import inv_sqrt  # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo  # noqa: E402
 from repro.launch.mesh import (  # noqa: E402
-    HW,
     make_production_mesh,
     model_axis,
     n_fl_nodes,
@@ -165,6 +164,10 @@ def build_train_lowering(arch: str, shape_name: str, mesh, q: int, algorithm: st
                 f"this mesh has {mesh.axis_names!r}"
             )
         extra["model_axis"] = maxis
+    if fl_engine == "fused":
+        # the dense engine runs under GSPMD here, which partitions the
+        # jnp oracle but not a Pallas call
+        extra["impl"] = "jnp"
     engine = engine_cls.from_mesh(
         mesh, naxes, stacked_sds, specs=pspecs, wire_dtype=wire_dtype,
         axes_subset=("data",) if hier else None, scale_chunk=scale_chunk,
@@ -452,8 +455,6 @@ def run_pair(
         t_compile = time.time() - t0 - t_lower
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns a 1-list of dicts
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     # while-aware accounting (cost_analysis counts scan bodies once)
     hlo = analyze_hlo(compiled.as_text())

@@ -14,18 +14,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int):
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # jax 0.4.x: meshes are Auto-typed implicitly
-
-    def _axis_kw(n: int):
-        return {}
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "make_production_mesh",
@@ -34,23 +23,13 @@ __all__ = [
     "model_axis",
     "n_fl_nodes",
     "n_model_shards",
-    "HW",
 ]
-
-
-# TPU v5e hardware constants (per chip) used by the roofline analysis
-HW = {
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bw": 819e9,  # B/s
-    "ici_bw": 50e9,  # B/s per link (intra-pod)
-    "dci_bw": 9e9,  # B/s per link (inter-pod; hierarchical-gossip motivation)
-}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2, 2)) -> Mesh:
@@ -58,7 +37,7 @@ def make_test_mesh(shape: Tuple[int, ...] = (2, 2, 2)) -> Mesh:
     axes = ("pod", "data", "model")[-len(shape) :] if len(shape) < 3 else ("pod", "data", "model")
     if len(shape) == 2:
         axes = ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(shape)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def node_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -1,45 +1,50 @@
 """Training launcher: decentralized FL training of any registered arch.
 
-Two modes:
-  * ``--smoke`` (default): reduced config of the same family, real training
-    on the host devices (CPU in this container) with the simulated node
-    axis -- this is the end-to-end driver the examples use;
-  * full configs with ``--mesh single|multi``: builds the sharded FL round
-    (node-stacked state over (pod, data), ppermute gossip, Megatron TP) --
-    on TPU this trains; on CPU use launch/dryrun.py, which lowers the very
-    same round function.
+Every site is a row of the node-stacked state on the host's default
+device (``train_decentralized`` with the selected round engine), at the
+arch's published widths unless ``--smoke`` picks the reduced config of
+the same family. ``--storage-dtype bfloat16`` halves the stored
+parameter buffer of the fused engine when a published-width state would
+not fit otherwise. Multi-device meshes (one site per chip, ppermute
+gossip) are built by ``examples/train_100m.py --fl-engine sharded_fused``
+and ``chip_smoke.py --chips 4``; ``launch/dryrun.py`` lowers the same
+round for a production mesh.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
       --smoke --rounds 20 --q 4 --algorithm dsgt --nodes 8
+  PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
+      --nodes 2 --algorithm dsgd --q 2 --rounds 3 --fl-engine fused \
+      --storage-dtype bfloat16
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
-from typing import Dict
+from typing import Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import FLRunConfig, get_config
+from repro.configs.base import ModelConfig
 from repro.core.dynamics import program_names
 from repro.core.engine import engine_names, schedule_names
 from repro.core.heterogeneity import node_program_names
 from repro.data.tokens import make_fl_token_batches
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.training.checkpoint import save_fl_state
-from repro.training.trainer import train_decentralized
+from repro.training.trainer import TrainResult, train_decentralized
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the arch's reduced config (default: the "
+                         "published widths)")
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--q", type=int, default=4)
     ap.add_argument("--algorithm", default="dsgt", choices=("dsgd", "dsgt"))
@@ -112,9 +117,19 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--log-every", type=int, default=5)
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch, smoke=True)
+
+def resolve_config(args: argparse.Namespace) -> ModelConfig:
+    """The arch's published config, or its reduced one under --smoke."""
+    return get_config(args.arch, smoke=args.smoke)
+
+
+def train(args: argparse.Namespace) -> Tuple[Dict, TrainResult]:
+    """Run the decentralized training the arguments describe. Returns the
+    JSON summary and the :class:`TrainResult` (final state, engine, the
+    jitted round)."""
+    cfg = resolve_config(args)
     bundle = build_model(cfg)
     run = FLRunConfig(
         algorithm=args.algorithm,
@@ -125,7 +140,6 @@ def main() -> None:
         alpha0=args.alpha0,
         seed=args.seed,
     )
-    params = bundle.init_fn(jax.random.key(args.seed))
 
     extras: Dict[str, tuple] = {}
     if cfg.family == "vlm":
@@ -152,8 +166,11 @@ def main() -> None:
                 "bounded_staleness:k=K; pass one or the other"
             )
         fl_schedule = None  # trainer derives it from staleness_depth
+    # the initial params are handed over, not kept: the trainer frees the
+    # tree once the state holds them
     result = train_decentralized(
-        bundle.loss_fn, params, run, step_batches(), rounds=args.rounds,
+        bundle.loss_fn, bundle.init_fn(jax.random.key(args.seed)), run,
+        step_batches(), rounds=args.rounds,
         log_every=args.log_every, engine=args.fl_engine,
         scale_chunk=args.scale_chunk, topk=args.topk,
         round_schedule=fl_schedule, storage_dtype=args.storage_dtype,
@@ -166,33 +183,36 @@ def main() -> None:
     )
     hist = result.history
     first, last = hist.rows()[0], hist.last()
-    print(
-        json.dumps(
-            {
-                "arch": cfg.name,
-                "fl_engine": args.fl_engine,
-                "fl_schedule": result.engine.round_schedule.spec(),
-                "fl_topology_program": args.fl_topology_program,
-                "fl_node_program": args.fl_node_program,
-                "fl_privacy": result.engine.privacy.spec(),
-                "fl_scope": result.engine.scope.spec(),
-                "algorithm": args.algorithm,
-                "q": args.q,
-                "rounds": args.rounds,
-                "iterations": int(last["iteration"]),
-                "loss_first": first["loss"],
-                "loss_last": last["loss"],
-                "consensus_err_last": last["consensus_err"],
-                "dp_epsilon": last.get("dp_epsilon"),
-                "wall_s": round(time.time() - t0, 1),
-            },
-            indent=2,
-        )
-    )
+    summary = {
+        "arch": cfg.name,
+        "fl_engine": args.fl_engine,
+        "fl_schedule": result.engine.round_schedule.spec(),
+        "fl_topology_program": args.fl_topology_program,
+        "fl_node_program": args.fl_node_program,
+        "fl_privacy": result.engine.privacy.spec(),
+        "fl_scope": result.engine.scope.spec(),
+        "algorithm": args.algorithm,
+        "q": args.q,
+        "rounds": args.rounds,
+        "iterations": int(last["iteration"]),
+        "loss_first": first["loss"],
+        "loss_last": last["loss"],
+        "consensus_err_last": last["consensus_err"],
+        "dp_epsilon": last.get("dp_epsilon"),
+        "wall_s": round(time.time() - t0, 1),
+    }
     if args.checkpoint:
         save_fl_state(args.checkpoint, result.state, extra={"arch": cfg.name},
                       engine=result.engine)
-        print(f"checkpoint -> {args.checkpoint}")
+        summary["checkpoint"] = args.checkpoint
+    return summary, result
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    summary, _ = train(args)
+    print(json.dumps(summary, indent=2))
 
 
 if __name__ == "__main__":

@@ -113,6 +113,7 @@ class TrainResult:
     consensus: PyTree
     w: np.ndarray
     engine: GossipEngine = None  # the engine the run trained with
+    round_fn: Callable = None  # the jitted round (lower it to read its HLO)
 
 
 def make_schedule(run: FLRunConfig):
@@ -286,6 +287,10 @@ def train_decentralized(
             privacy=privacy, scope=scope,
         )
         engine, params0 = build(w, stacked, topk=topk, **kw)
+    caller_ids = {id(l) for l in jax.tree_util.tree_leaves(params_single)}
+    if any(id(l) in caller_ids for l in jax.tree_util.tree_leaves(params0)):
+        # the round donates its state: never hand it the caller's arrays
+        params0 = jax.tree_util.tree_map(jnp.copy, params0)
     schedule = make_schedule(run)
     if robust_alpha:
         from repro.core.schedules import robust_alpha_scale, scaled
@@ -296,7 +301,12 @@ def train_decentralized(
             schedule,
             robust_alpha_scale(uptime, engine.round_schedule.depth),
         )
-    round_fn = jax.jit(make_fl_round(loss_fn, None, schedule, cfg, engine=engine))
+    # the round consumes its input state: donating it keeps one state, not
+    # two, on the device (at published widths the state is most of HBM)
+    round_fn = jax.jit(
+        make_fl_round(loss_fn, None, schedule, cfg, engine=engine),
+        donate_argnums=(0,),
+    )
     adaptive, dense_fn = None, None
     if topk_schedule is not None:
         adaptive = AdaptiveTopK(topk_schedule, engine.scale_chunk)
@@ -304,16 +314,19 @@ def train_decentralized(
         # depend on k), so both round functions advance the SAME state
         dense_engine, _ = build(w, stacked, topk=adaptive.dense_topk, **kw)
         dense_fn = jax.jit(
-            make_fl_round(loss_fn, None, schedule, cfg, engine=dense_engine)
+            make_fl_round(loss_fn, None, schedule, cfg, engine=dense_engine),
+            donate_argnums=(0,),
         )
-    state = init_fl_state(cfg, params0, engine=engine)
-
     fallback_bytes = engine.wire_bytes(cfg)
     if fallback_bytes is None:
         fallback_bytes = comm_bytes_per_gossip(
             params_single, run.topology, run.n_nodes,
             wire_dtype=str(np.dtype(wire_dtype)) if wire_dtype else None,
         )
+    # drop the tree views: from here the state alone holds the parameters
+    del params_single, stacked
+    state = init_fl_state(cfg, params0, engine=engine)
+    del params0
     history = MetricHistory()
     t0 = time.time()
     cum_bytes = 0.0
@@ -352,7 +365,8 @@ def train_decentralized(
                 f"cons={row['consensus_err']:.3e} gnorm2={row['grad_norm_sq']:.3e}"
             )
     return TrainResult(state=state, history=history,
-                       consensus=_consensus(engine, state), w=w, engine=engine)
+                       consensus=_consensus(engine, state), w=w, engine=engine,
+                       round_fn=round_fn)
 
 
 def _consensus(engine: GossipEngine, state: FLState) -> PyTree:

@@ -12,13 +12,6 @@ def _compile(f, *args):
     return jax.jit(f).lower(*args).compile()
 
 
-def _cost(compiled):
-    """compiled.cost_analysis() returns a dict (jax >= 0.5) or a 1-list of
-    dicts (jax 0.4.x)."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_scan_flops_match_unrolled():
     def f_scan(x, w):
         def body(h, _):
@@ -36,7 +29,7 @@ def test_scan_flops_match_unrolled():
     ws = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     a_scan = analyze_hlo(_compile(f_scan, xs, ws).as_text())
     c_unroll = _compile(f_unroll, xs, ws)
-    truth = _cost(c_unroll)["flops"]
+    truth = c_unroll.cost_analysis()["flops"]
     dot_flops = 9 * 2 * 64 * 128 * 128
     assert abs(a_scan.flops - truth) / truth < 0.02
     assert a_scan.flops >= dot_flops
