@@ -163,11 +163,37 @@ __all__ = [
     "get_schedule",
     "schedule_names",
     "resolve_schedule",
+    "STAGES",
 ]
 
 
 def _tm(f, *trees):
     return jax.tree_util.tree_map(f, *trees)
+
+
+# ---------------------------------------------------------------------------
+# Round stages
+# ---------------------------------------------------------------------------
+#
+# The round's device work carries one of four stage names, each opened as a
+# ``jax.named_scope``. The name lands in the ``op_name`` metadata of every
+# compiled instruction traced under it (fusions, ``while`` loops and their
+# bodies included), which is how a device trace attributes time to the
+# stages. A scope adds metadata only: the compiled program is otherwise the
+# same. Where stages nest -- a collective inside the sharded engine's wire
+# stage -- the innermost names the instruction.
+
+#: the local steps' forward and backward: the Q-1 step scan and the comm
+#: step's gradient (``make_fl_round`` wraps ``eval_grads``)
+STAGE_LOCAL = "fl_local"
+#: the wire stage: input casts and column gathers, the round or stage
+#: kernel (or the exact-wire mix), the scope epilogue, the storage casts
+STAGE_WIRE = "fl_wire"
+#: every collective that moves the payload, and the pipelined ingest
+STAGE_TRANSPORT = "fl_transport"
+#: the round's metrics dict
+STAGE_METRICS = "fl_metrics"
+STAGES = (STAGE_LOCAL, STAGE_WIRE, STAGE_TRANSPORT, STAGE_METRICS)
 
 
 # ---------------------------------------------------------------------------
@@ -296,35 +322,41 @@ def _assemble_round(cfg, local_step, comm_call, pre_scan=None,
     scan, never as a recompile."""
 
     def round_fn(state: FLState, batches: PyTree):
-        aux = pre_scan(state) if pre_scan is not None else None
+        aux = None
+        if pre_scan is not None:
+            with jax.named_scope(STAGE_TRANSPORT):
+                aux = pre_scan(state)
         q = cfg.q
-        mask = step_mask(state) if step_mask is not None else None
-        if q > 1:
-            local_batches = _tm(lambda b: b[: q - 1], batches)
-            if mask is None:
-                state, local_losses = jax.lax.scan(
-                    local_step, state, local_batches
-                )
+        with jax.named_scope(STAGE_LOCAL):
+            mask = step_mask(state) if step_mask is not None else None
+            if q > 1:
+                local_batches = _tm(lambda b: b[: q - 1], batches)
+                if mask is None:
+                    state, local_losses = jax.lax.scan(
+                        local_step, state, local_batches
+                    )
+                else:
+                    state, local_losses = jax.lax.scan(
+                        lambda c, xs: local_step(c, xs[0], mask=xs[1]),
+                        state, (local_batches, mask),
+                    )
             else:
-                state, local_losses = jax.lax.scan(
-                    lambda c, xs: local_step(c, xs[0], mask=xs[1]),
-                    state, (local_batches, mask),
-                )
-        else:
-            local_losses = jnp.zeros((0,), jnp.float32)
-        comm_batch = _tm(lambda b: b[q - 1], batches)
+                local_losses = jnp.zeros((0,), jnp.float32)
+            comm_batch = _tm(lambda b: b[q - 1], batches)
         state, metrics = comm_call(state, comm_batch, aux)
-        metrics["local_loss"] = jnp.where(
-            q > 1,
-            jnp.sum(local_losses) / jnp.maximum(1, q - 1),
-            metrics["loss"],
-        )
-        if mask is not None:
-            # realized local-step work: masked scan iterations + the comm
-            # step's own update, as a fraction of the homogeneous q * n
-            metrics["compute_fraction"] = (
-                jnp.sum(mask.astype(jnp.float32)) + cfg.n_nodes
-            ) / jnp.float32(q * cfg.n_nodes)
+        with jax.named_scope(STAGE_METRICS):
+            metrics["local_loss"] = jnp.where(
+                q > 1,
+                jnp.sum(local_losses) / jnp.maximum(1, q - 1),
+                metrics["loss"],
+            )
+            if mask is not None:
+                # realized local-step work: masked scan iterations + the
+                # comm step's own update, as a fraction of the homogeneous
+                # q * n
+                metrics["compute_fraction"] = (
+                    jnp.sum(mask.astype(jnp.float32)) + cfg.n_nodes
+                ) / jnp.float32(q * cfg.n_nodes)
         return state, metrics
 
     return round_fn
@@ -848,43 +880,65 @@ class GossipEngine(abc.ABC):
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
 
-            gate_metrics: Dict[str, jnp.ndarray] = {}
-            if not dynamic:
-                mix, comm = self.mix, state.comm
-            else:
-                w_off_r, w_diag_r, new_entries, gate_metrics = (
-                    self._round_gates(state.comm)
-                )
-                mix = lambda buf: self.mix_dynamic(buf, w_off_r, w_diag_r)
-                comm = dict(state.comm)
-                comm.update(new_entries)
+            with jax.named_scope(STAGE_WIRE):
+                gate_metrics: Dict[str, jnp.ndarray] = {}
+                if not dynamic:
+                    mix, comm = self.mix, state.comm
+                else:
+                    w_off_r, w_diag_r, new_entries, gate_metrics = (
+                        self._round_gates(state.comm)
+                    )
+                    mix = lambda buf: self.mix_dynamic(
+                        buf, w_off_r, w_diag_r
+                    )
+                    comm = dict(state.comm)
+                    comm.update(new_entries)
 
-            # adapt at fp32, store back at the state dtype (bf16 flat
-            # storage narrows only what is STORED, never the arithmetic)
-            def adapt(wp, t):
-                return (
-                    wp.astype(jnp.float32) - alpha * t.astype(jnp.float32)
-                ).astype(wp.dtype)
+                # adapt at fp32, store back at the state dtype (bf16 flat
+                # storage narrows only what is STORED, never the
+                # arithmetic)
+                def adapt(wp, t):
+                    return (
+                        wp.astype(jnp.float32)
+                        - alpha * t.astype(jnp.float32)
+                    ).astype(wp.dtype)
 
-            if cfg.algorithm == "dsgd":
-                params = _tm(adapt, mix(state.params), grads)
-                new_state = state._replace(step=step, params=params, comm=comm)
-            else:
-                tracker = _tm(
-                    lambda wt, gn, gp: wt + gn.astype(wt.dtype) - gp,
-                    mix(state.tracker), grads, state.prev_grad,
-                )
-                params = _tm(adapt, mix(state.params), tracker)
-                new_state = state._replace(
-                    step=step,
-                    params=params,
-                    tracker=tracker,
-                    prev_grad=_tm(
-                        lambda g, p: g.astype(p.dtype), grads, state.prev_grad
-                    ),
-                    comm=comm,
-                )
+                if cfg.algorithm == "dsgd":
+                    params = _tm(adapt, mix(state.params), grads)
+                    new_state = state._replace(
+                        step=step, params=params, comm=comm
+                    )
+                else:
+                    tracker = _tm(
+                        lambda wt, gn, gp: wt + gn.astype(wt.dtype) - gp,
+                        mix(state.tracker), grads, state.prev_grad,
+                    )
+                    params = _tm(adapt, mix(state.params), tracker)
+                    new_state = state._replace(
+                        step=step,
+                        params=params,
+                        tracker=tracker,
+                        prev_grad=_tm(
+                            lambda g, p: g.astype(p.dtype), grads,
+                            state.prev_grad,
+                        ),
+                        comm=comm,
+                    )
 
+            return new_state, self._round_metrics(
+                cfg, losses, grads, alpha, new_state, wire, gate_metrics
+            )
+
+        return comm_step
+
+    def _round_metrics(self, cfg: FLConfig, losses, grads, alpha,
+                       new_state: FLState, egress, gate_metrics):
+        """The comm step's metrics dict, under the ``fl_metrics`` stage:
+        mean loss, ||mean_i grad_i||^2, consensus error, alpha,
+        comm_rounds, ``wire_bytes`` (when the engine accounts its wire,
+        ``egress`` not None), the engine's state metrics, then the round's
+        topology and node gate metrics."""
+        with jax.named_scope(STAGE_METRICS):
             metrics = {
                 "loss": jnp.mean(losses),
                 "alpha": alpha,
@@ -892,12 +946,17 @@ class GossipEngine(abc.ABC):
                 "consensus_err": _consensus_error(new_state.params),
                 "comm_rounds": jnp.float32(1.0),
             }
-            if wire is not None:
-                metrics["wire_bytes"] = jnp.float32(wire)
-            metrics.update(gate_metrics)
-            return new_state, metrics
+            if egress is not None:
+                metrics["wire_bytes"] = jnp.float32(egress)
+            metrics.update(self._state_metrics(cfg, new_state))
+        metrics.update(gate_metrics)
+        return metrics
 
-        return comm_step
+    def _state_metrics(self, cfg: FLConfig,
+                       new_state: FLState) -> Dict[str, jnp.ndarray]:
+        """Metrics of the engine's own wire state (none for the exact
+        wire)."""
+        return {}
 
     def make_pipelined_round(self, eval_grads, schedule, cfg: FLConfig):
         """The split comm machinery the :class:`PipelinedSchedule` needs:
@@ -1612,12 +1671,19 @@ class _FusedBase(GossipEngine):
     def _st(self, x: jnp.ndarray) -> jnp.ndarray:
         return x if x.dtype == self._store else x.astype(self._store)
 
-    def _residual_rms(self, comm: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        """RMS of the parameter-wire EF residual -- the adaptive-k signal
-        (``topk_schedule``): a large residual means the wire is dropping
-        mass faster than EF re-injects it, so the schedule densifies k."""
-        res = comm["residual"]
-        return jnp.sqrt(jnp.mean(res.astype(jnp.float32) ** 2))
+    def _state_metrics(self, cfg: FLConfig,
+                       new_state: FLState) -> Dict[str, jnp.ndarray]:
+        """``ef_residual_rms``, the RMS of the parameter-wire EF residual
+        -- the adaptive-k signal (``topk_schedule``): a large residual
+        means the wire is dropping mass faster than EF re-injects it, so
+        the schedule densifies k -- and the privacy metrics."""
+        res = new_state.comm["residual"]
+        return {
+            "ef_residual_rms": jnp.sqrt(
+                jnp.mean(res.astype(jnp.float32) ** 2)
+            ),
+            **self._privacy_metrics(cfg, new_state),
+        }
 
 
 @register_engine
@@ -1723,79 +1789,71 @@ class FusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
 
-            # Dynamic topology / node gates: the kernels already take
-            # (w_off, w_self) as runtime operands, so the per-round
-            # realized W is simply the traced program output -- same
-            # kernel, same compilation, all rounds.
-            gate_metrics: Dict[str, jnp.ndarray] = {}
-            if dynamic:
-                w_off_r, w_self_r, topo_comm, gate_metrics = (
-                    self._round_gates(state.comm)
-                )
-            else:
-                w_off_r, w_self_r = w_off, w_self
-                topo_comm = self._priv_comm(state.comm)
-            dpkw = dict(self._dp_kwargs())
-            if dp:
-                dpkw["dp_noise"] = self._dp_noise_full(state.comm, n)
-            # Scope: the kernel runs UNCHANGED on the gathered shared
-            # columns; private columns never enter it and are rebuilt by
-            # _scope_finish[_gt] from the plain local update.
-            fire = self._scope_fire(state.comm)
-
-            if cfg.algorithm == "dsgd":
-                mixed, recon, res, _ = fused_round(
-                    self._gather_cols(self._f32(state.params)),
-                    self._gather_cols(grads), state.comm["recon"],
-                    state.comm["residual"], w_off_r, w_self_r, alpha,
-                    **kw, **dpkw,
-                )
-                mixed = self._scope_finish(
-                    mixed, state.params, grads, alpha, fire
-                )
-                new_state = state._replace(
-                    step=step, params=self._st(mixed),
-                    comm={"recon": recon, "residual": res, **topo_comm},
-                )
-            else:
-                if dp:
-                    dpkw["dp_noise_t"] = self._dp_noise_full(
-                        state.comm, n, tracker=True
+                # Dynamic topology / node gates: the kernels already take
+                # (w_off, w_self) as runtime operands, so the per-round
+                # realized W is simply the traced program output -- same
+                # kernel, same compilation, all rounds.
+                gate_metrics: Dict[str, jnp.ndarray] = {}
+                if dynamic:
+                    w_off_r, w_self_r, topo_comm, gate_metrics = (
+                        self._round_gates(state.comm)
                     )
-                mx, mt, nrx, nsx, nrt, nst, _, _ = fused_round_gt(
-                    self._gather_cols(self._f32(state.params)),
-                    self._gather_cols(self._f32(state.tracker)),
-                    self._gather_cols(grads),
-                    self._gather_cols(self._f32(state.prev_grad)),
-                    state.comm["recon"], state.comm["residual"],
-                    state.comm["recon_t"], state.comm["residual_t"],
-                    w_off_r, w_self_r, alpha, **kw, **dpkw,
-                )
-                mx, mt = self._scope_finish_gt(
-                    mx, mt, state.params, state.tracker, grads,
-                    state.prev_grad, alpha, fire,
-                )
-                new_state = FLState(
-                    step=step, params=self._st(mx), tracker=self._st(mt),
-                    prev_grad=self._st(grads),
-                    comm={"recon": nrx, "residual": nsx,
-                          "recon_t": nrt, "residual_t": nst, **topo_comm},
-                )
+                else:
+                    w_off_r, w_self_r = w_off, w_self
+                    topo_comm = self._priv_comm(state.comm)
+                dpkw = dict(self._dp_kwargs())
+                if dp:
+                    dpkw["dp_noise"] = self._dp_noise_full(state.comm, n)
+                # Scope: the kernel runs UNCHANGED on the gathered shared
+                # columns; private columns never enter it and are rebuilt by
+                # _scope_finish[_gt] from the plain local update.
+                fire = self._scope_fire(state.comm)
 
-            metrics = {
-                "loss": jnp.mean(losses),
-                "alpha": alpha,
-                "grad_norm_sq": _mean_grad_norm_sq(grads),
-                "consensus_err": _consensus_error(new_state.params),
-                "comm_rounds": jnp.float32(1.0),
-                "wire_bytes": jnp.float32(egress),
-                "ef_residual_rms": self._residual_rms(new_state.comm),
-            }
-            metrics.update(self._privacy_metrics(cfg, new_state))
-            metrics.update(gate_metrics)
-            return new_state, metrics
+                if cfg.algorithm == "dsgd":
+                    mixed, recon, res, _ = fused_round(
+                        self._gather_cols(self._f32(state.params)),
+                        self._gather_cols(grads), state.comm["recon"],
+                        state.comm["residual"], w_off_r, w_self_r, alpha,
+                        **kw, **dpkw,
+                    )
+                    mixed = self._scope_finish(
+                        mixed, state.params, grads, alpha, fire
+                    )
+                    new_state = state._replace(
+                        step=step, params=self._st(mixed),
+                        comm={"recon": recon, "residual": res, **topo_comm},
+                    )
+                else:
+                    if dp:
+                        dpkw["dp_noise_t"] = self._dp_noise_full(
+                            state.comm, n, tracker=True
+                        )
+                    mx, mt, nrx, nsx, nrt, nst, _, _ = fused_round_gt(
+                        self._gather_cols(self._f32(state.params)),
+                        self._gather_cols(self._f32(state.tracker)),
+                        self._gather_cols(grads),
+                        self._gather_cols(self._f32(state.prev_grad)),
+                        state.comm["recon"], state.comm["residual"],
+                        state.comm["recon_t"], state.comm["residual_t"],
+                        w_off_r, w_self_r, alpha, **kw, **dpkw,
+                    )
+                    mx, mt = self._scope_finish_gt(
+                        mx, mt, state.params, state.tracker, grads,
+                        state.prev_grad, alpha, fire,
+                    )
+                    new_state = FLState(
+                        step=step, params=self._st(mx), tracker=self._st(mt),
+                        prev_grad=self._st(grads),
+                        comm={"recon": nrx, "residual": nsx,
+                              "recon_t": nrt, "residual_t": nst, **topo_comm},
+                    )
+
+            return new_state, self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, gate_metrics
+            )
 
         return comm_step
 
@@ -1850,95 +1908,91 @@ class FusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
-            alpha32 = jnp.asarray(alpha, jnp.float32)
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
+                alpha32 = jnp.asarray(alpha, jnp.float32)
 
-            gate_metrics: Dict[str, jnp.ndarray] = {}
-            if dynamic:
-                w_off_r, w_self_r, topo_comm, gate_metrics = (
-                    self._round_gates(state.comm)
-                )
-                w_off_r = jnp.asarray(w_off_r, jnp.float32)
-                w_self_r = jnp.asarray(w_self_r, jnp.float32)
-            else:
-                w_off_r, w_self_r = w_off32, w_self32
-                topo_comm = self._priv_comm(state.comm)
-            dpkw = dict(self._dp_kwargs())
-            if dp:
-                dpkw["dp_noise"] = self._dp_noise_full(state.comm, n)
-            fire = self._scope_fire(state.comm)
-
-            c = state.comm
-            if cfg.algorithm == "dsgd":
-                h, q, sc, nrecon, nres = wire_stage(
-                    self._gather_cols(self._f32(state.params)),
-                    self._gather_cols(grads), c["recon"],
-                    c["residual"], alpha32, **kw, **dpkw,
-                )
-                mix = stale_recon(c["recon"], c["wire_q"], c["wire_scales"])
-                mixed = self._st(self._scope_finish(
-                    w_off_r @ mix + w_self_r[:, None] * h,
-                    state.params, grads, alpha32, fire,
-                ))
-                nwq, nwsc = push(c["wire_q"], c["wire_scales"], q, sc)
-                new_state = state._replace(
-                    step=step, params=mixed,
-                    comm={"recon": nrecon, "residual": nres,
-                          "wire_q": nwq, "wire_scales": nwsc, **topo_comm},
-                )
-            else:
+                gate_metrics: Dict[str, jnp.ndarray] = {}
+                if dynamic:
+                    w_off_r, w_self_r, topo_comm, gate_metrics = (
+                        self._round_gates(state.comm)
+                    )
+                    w_off_r = jnp.asarray(w_off_r, jnp.float32)
+                    w_self_r = jnp.asarray(w_self_r, jnp.float32)
+                else:
+                    w_off_r, w_self_r = w_off32, w_self32
+                    topo_comm = self._priv_comm(state.comm)
+                dpkw = dict(self._dp_kwargs())
                 if dp:
-                    dpkw["dp_noise_t"] = self._dp_noise_full(
-                        state.comm, n, tracker=True
-                    )
-                (h, t_half, qx, scx, nrx, nsx, qt, sct, nrt, nst) = (
-                    wire_stage_gt(
-                        self._gather_cols(self._f32(state.params)),
-                        self._gather_cols(self._f32(state.tracker)),
-                        self._gather_cols(grads),
-                        self._gather_cols(self._f32(state.prev_grad)),
-                        c["recon"], c["residual"], c["recon_t"],
-                        c["residual_t"], alpha32, **kw, **dpkw,
-                    )
-                )
-                mix_x = stale_recon(c["recon"], c["wire_q"], c["wire_scales"])
-                mix_t = stale_recon(
-                    c["recon_t"], c["wire_q_t"], c["wire_scales_t"]
-                )
-                mixed_x, mixed_t = self._scope_finish_gt(
-                    w_off_r @ mix_x + w_self_r[:, None] * h,
-                    w_off_r @ mix_t + w_self_r[:, None] * t_half,
-                    state.params, state.tracker, grads, state.prev_grad,
-                    alpha32, fire,
-                )
-                mixed_x = self._st(mixed_x)
-                mixed_t = self._st(mixed_t)
-                nwq, nwsc = push(c["wire_q"], c["wire_scales"], qx, scx)
-                nwqt, nwsct = push(
-                    c["wire_q_t"], c["wire_scales_t"], qt, sct
-                )
-                new_state = FLState(
-                    step=step, params=mixed_x, tracker=mixed_t,
-                    prev_grad=self._st(grads),
-                    comm={"recon": nrx, "residual": nsx,
-                          "recon_t": nrt, "residual_t": nst,
-                          "wire_q": nwq, "wire_scales": nwsc,
-                          "wire_q_t": nwqt, "wire_scales_t": nwsct,
-                          **topo_comm},
-                )
+                    dpkw["dp_noise"] = self._dp_noise_full(state.comm, n)
+                fire = self._scope_fire(state.comm)
 
-            metrics = {
-                "loss": jnp.mean(losses),
-                "alpha": alpha,
-                "grad_norm_sq": _mean_grad_norm_sq(grads),
-                "consensus_err": _consensus_error(new_state.params),
-                "comm_rounds": jnp.float32(1.0),
-                "wire_bytes": jnp.float32(egress),
-                "ef_residual_rms": self._residual_rms(new_state.comm),
-            }
-            metrics.update(self._privacy_metrics(cfg, new_state))
-            metrics.update(gate_metrics)
-            return new_state, metrics
+                c = state.comm
+                if cfg.algorithm == "dsgd":
+                    h, q, sc, nrecon, nres = wire_stage(
+                        self._gather_cols(self._f32(state.params)),
+                        self._gather_cols(grads), c["recon"],
+                        c["residual"], alpha32, **kw, **dpkw,
+                    )
+                    mix = stale_recon(
+                        c["recon"], c["wire_q"], c["wire_scales"]
+                    )
+                    mixed = self._st(self._scope_finish(
+                        w_off_r @ mix + w_self_r[:, None] * h,
+                        state.params, grads, alpha32, fire,
+                    ))
+                    nwq, nwsc = push(c["wire_q"], c["wire_scales"], q, sc)
+                    new_state = state._replace(
+                        step=step, params=mixed,
+                        comm={"recon": nrecon, "residual": nres,
+                              "wire_q": nwq, "wire_scales": nwsc, **topo_comm},
+                    )
+                else:
+                    if dp:
+                        dpkw["dp_noise_t"] = self._dp_noise_full(
+                            state.comm, n, tracker=True
+                        )
+                    (h, t_half, qx, scx, nrx, nsx, qt, sct, nrt, nst) = (
+                        wire_stage_gt(
+                            self._gather_cols(self._f32(state.params)),
+                            self._gather_cols(self._f32(state.tracker)),
+                            self._gather_cols(grads),
+                            self._gather_cols(self._f32(state.prev_grad)),
+                            c["recon"], c["residual"], c["recon_t"],
+                            c["residual_t"], alpha32, **kw, **dpkw,
+                        )
+                    )
+                    mix_x = stale_recon(
+                        c["recon"], c["wire_q"], c["wire_scales"]
+                    )
+                    mix_t = stale_recon(
+                        c["recon_t"], c["wire_q_t"], c["wire_scales_t"]
+                    )
+                    mixed_x, mixed_t = self._scope_finish_gt(
+                        w_off_r @ mix_x + w_self_r[:, None] * h,
+                        w_off_r @ mix_t + w_self_r[:, None] * t_half,
+                        state.params, state.tracker, grads, state.prev_grad,
+                        alpha32, fire,
+                    )
+                    mixed_x = self._st(mixed_x)
+                    mixed_t = self._st(mixed_t)
+                    nwq, nwsc = push(c["wire_q"], c["wire_scales"], qx, scx)
+                    nwqt, nwsct = push(
+                        c["wire_q_t"], c["wire_scales_t"], qt, sct
+                    )
+                    new_state = FLState(
+                        step=step, params=mixed_x, tracker=mixed_t,
+                        prev_grad=self._st(grads),
+                        comm={"recon": nrx, "residual": nsx,
+                              "recon_t": nrt, "residual_t": nst,
+                              "wire_q": nwq, "wire_scales": nwsc,
+                              "wire_q_t": nwqt, "wire_scales_t": nwsct,
+                              **topo_comm},
+                    )
+
+            return new_state, self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, gate_metrics
+            )
 
         return comm_step
 
@@ -2541,24 +2595,25 @@ class ShardedFusedEngine(_FusedBase):
         axis_name, shift, _w = self.dirs[d]
         size = self.mesh.shape[axis_name]
         perm = [(i, (i + shift) % size) for i in range(size)]
-        if priv is not None:
-            key, r = priv
-            n = self.n_nodes
-            my = self._my_index()
-            dst = jnp.asarray(self._dir_dst[d])[my]
-            wire = mask_wire(
-                wire, key, r, pair_index(my, dst, n), my < dst,
-                stream_base=stream_base,
+        with jax.named_scope(STAGE_TRANSPORT):
+            if priv is not None:
+                key, r = priv
+                n = self.n_nodes
+                my = self._my_index()
+                dst = jnp.asarray(self._dir_dst[d])[my]
+                wire = mask_wire(
+                    wire, key, r, pair_index(my, dst, n), my < dst,
+                    stream_base=stream_base,
+                )
+            recv = tuple(
+                jax.lax.ppermute(b, axis_name, perm) for b in wire
             )
-        recv = tuple(
-            jax.lax.ppermute(b, axis_name, perm) for b in wire
-        )
-        if priv is not None:
-            src = jnp.asarray(self._dir_src[d])[my]
-            recv = mask_wire(
-                recv, key, r, pair_index(src, my, n), src < my,
-                stream_base=stream_base, unmask=True,
-            )
+            if priv is not None:
+                src = jnp.asarray(self._dir_src[d])[my]
+                recv = mask_wire(
+                    recv, key, r, pair_index(src, my, n), src < my,
+                    stream_base=stream_base, unmask=True,
+                )
         return recv
 
     def _wire_mix(self, wire: Tuple[jnp.ndarray, ...], w_off_rows,
@@ -2585,12 +2640,12 @@ class ShardedFusedEngine(_FusedBase):
         # arbitrary dense W: ONE all-gather per wire buffer (secure_agg
         # is rejected at build on this wire -- nothing to pad)
         n = self.n_nodes
-        gathered = tuple(
-            jax.lax.all_gather(b[0], self.node_axes, tiled=False).reshape(
-                n, -1
+        with jax.named_scope(STAGE_TRANSPORT):
+            gathered = tuple(
+                jax.lax.all_gather(b[0], self.node_axes, tiled=False)
+                .reshape(n, -1)
+                for b in wire
             )
-            for b in wire
-        )
         dq = self._dq_full(gathered)
         row = _allgather_row(self.mesh, self.node_axes, w_off_rows)  # (n,)
         return (row @ dq)[None]
@@ -2838,19 +2893,6 @@ class ShardedFusedEngine(_FusedBase):
             _, w_diag, w_off = _split_w_np(self.w_dense, self.n_nodes)
         return w_diag, w_off
 
-    def _metrics(self, cfg, losses, grads, alpha, new_state, egress):
-        m = {
-            "loss": jnp.mean(losses),
-            "alpha": alpha,
-            "grad_norm_sq": _mean_grad_norm_sq(grads),
-            "consensus_err": _consensus_error(new_state.params),
-            "comm_rounds": jnp.float32(1.0),
-            "wire_bytes": jnp.float32(egress),
-            "ef_residual_rms": self._residual_rms(new_state.comm),
-        }
-        m.update(self._privacy_metrics(cfg, new_state))
-        return m
-
     def _mix_dirs_dynamic(self, dqs, nbrs, dgate):
         """Fold one wire's per-direction dq into the neighbor-recon
         accumulators and weight by the round's gate: ``mix_i = sum_d
@@ -3031,76 +3073,78 @@ class ShardedFusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
-            alpha32 = jnp.asarray(alpha, jnp.float32)
-            dgate, ddiag, topo_comm, gate_metrics = self._dir_gates(
-                state.comm
-            )
-            kops = (self._wire_k_vec(state.comm),) if wk else ()
-            adds = tuple(stale["dqs"]) if pipelined else ()
-            priv = (
-                (state.comm["priv_key"], state.comm["topo_round"])
-                if sa_body else ()
-            )
-            noises = (
-                (self._dp_noise_full(state.comm, cfg.n_nodes),) if dp else ()
-            )
-
-            if cfg.algorithm == "dsgd":
-                outs = sm_dsgd(
-                    self._f32(state.params), grads, state.comm["recon"],
-                    state.comm["residual"],
-                    *[state.comm[k] for k in nbr_keys],
-                    *adds, dgate, ddiag, *kops, alpha32, *priv, *noises,
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
+                alpha32 = jnp.asarray(alpha, jnp.float32)
+                dgate, ddiag, topo_comm, gate_metrics = self._dir_gates(
+                    state.comm
                 )
-                mixed, nrecon, nres = outs[:3]
-                comm = {"recon": nrecon, "residual": nres, **topo_comm}
-                # output order == key order by construction of the bodies
-                comm.update(zip(nbr_keys, outs[3:3 + nnbr]))
-                self._push_wire(
-                    state.comm, comm, wire_keys, outs[3 + nnbr:]
+                kops = (self._wire_k_vec(state.comm),) if wk else ()
+                adds = tuple(stale["dqs"]) if pipelined else ()
+                priv = (
+                    (state.comm["priv_key"], state.comm["topo_round"])
+                    if sa_body else ()
                 )
-                new_state = state._replace(
-                    step=step, params=self._st(mixed), comm=comm
-                )
-            else:
-                adds_t = tuple(stale["dqs_t"]) if pipelined else ()
-                if dp:
-                    noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
-                                                   tracker=True),)
-                outs = sm_dsgt(
-                    self._f32(state.params), self._f32(state.tracker),
-                    grads, self._f32(state.prev_grad),
-                    state.comm["recon"], state.comm["residual"],
-                    state.comm["recon_t"], state.comm["residual_t"],
-                    *[state.comm[k] for k in nbr_keys],
-                    *[state.comm[k] for k in nbr_keys_t],
-                    *adds, *adds_t, dgate, ddiag, *kops, alpha32,
-                    *priv, *noises,
-                )
-                (mx, mt, nrx, nsx, nrt, nst) = outs[:6]
-                comm = {"recon": nrx, "residual": nsx,
-                        "recon_t": nrt, "residual_t": nst, **topo_comm}
-                comm.update(zip(
-                    nbr_keys + nbr_keys_t, outs[6:6 + 2 * nnbr]
-                ))
-                self._push_wire(
-                    state.comm, comm, wire_keys + wire_keys_t,
-                    outs[6 + 2 * nnbr:],
-                )
-                new_state = FLState(
-                    step=step, params=self._st(mx), tracker=self._st(mt),
-                    prev_grad=self._st(grads), comm=comm,
+                noises = (
+                    (self._dp_noise_full(state.comm, cfg.n_nodes),)
+                    if dp else ()
                 )
 
-            metrics = self._metrics(
-                cfg, losses, grads, alpha, new_state, egress
+                if cfg.algorithm == "dsgd":
+                    outs = sm_dsgd(
+                        self._f32(state.params), grads, state.comm["recon"],
+                        state.comm["residual"],
+                        *[state.comm[k] for k in nbr_keys],
+                        *adds, dgate, ddiag, *kops, alpha32, *priv, *noises,
+                    )
+                    mixed, nrecon, nres = outs[:3]
+                    comm = {"recon": nrecon, "residual": nres, **topo_comm}
+                    # output order == key order by construction of the bodies
+                    comm.update(zip(nbr_keys, outs[3:3 + nnbr]))
+                    self._push_wire(
+                        state.comm, comm, wire_keys, outs[3 + nnbr:]
+                    )
+                    new_state = state._replace(
+                        step=step, params=self._st(mixed), comm=comm
+                    )
+                else:
+                    adds_t = tuple(stale["dqs_t"]) if pipelined else ()
+                    if dp:
+                        noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
+                                                       tracker=True),)
+                    outs = sm_dsgt(
+                        self._f32(state.params), self._f32(state.tracker),
+                        grads, self._f32(state.prev_grad),
+                        state.comm["recon"], state.comm["residual"],
+                        state.comm["recon_t"], state.comm["residual_t"],
+                        *[state.comm[k] for k in nbr_keys],
+                        *[state.comm[k] for k in nbr_keys_t],
+                        *adds, *adds_t, dgate, ddiag, *kops, alpha32,
+                        *priv, *noises,
+                    )
+                    (mx, mt, nrx, nsx, nrt, nst) = outs[:6]
+                    comm = {"recon": nrx, "residual": nsx,
+                            "recon_t": nrt, "residual_t": nst, **topo_comm}
+                    comm.update(zip(
+                        nbr_keys + nbr_keys_t, outs[6:6 + 2 * nnbr]
+                    ))
+                    self._push_wire(
+                        state.comm, comm, wire_keys + wire_keys_t,
+                        outs[6 + 2 * nnbr:],
+                    )
+                    new_state = FLState(
+                        step=step, params=self._st(mx), tracker=self._st(mt),
+                        prev_grad=self._st(grads), comm=comm,
+                    )
+
+            metrics = self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, gate_metrics
             )
-            metrics.update(gate_metrics)
             if wk:
-                metrics["wire_bytes_effective"] = self._wire_k_bytes(
-                    kops[0], wires=2 if cfg.algorithm == "dsgt" else 1
-                )
+                with jax.named_scope(STAGE_METRICS):
+                    metrics["wire_bytes_effective"] = self._wire_k_bytes(
+                        kops[0], wires=2 if cfg.algorithm == "dsgt" else 1
+                    )
             return new_state, metrics
 
         return ingest, comm_step
@@ -3139,12 +3183,13 @@ class ShardedFusedEngine(_FusedBase):
 
         def gather_dq(wire):
             """ONE all-gather per wire buffer -> every node's dense dq."""
-            gathered = tuple(
-                jax.lax.all_gather(
-                    b[0], self.node_axes, tiled=False
-                ).reshape(n, -1)
-                for b in wire
-            )
+            with jax.named_scope(STAGE_TRANSPORT):
+                gathered = tuple(
+                    jax.lax.all_gather(
+                        b[0], self.node_axes, tiled=False
+                    ).reshape(n, -1)
+                    for b in wire
+                )
             return self._dq_full(gathered)
 
         def mix_one(wire, stale_wire, nbr, w_row):
@@ -3218,77 +3263,80 @@ class ShardedFusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
-            alpha32 = jnp.asarray(alpha, jnp.float32)
-            w_off_r, w_diag_r, topo_comm, gate_metrics = self._round_gates(
-                state.comm
-            )
-            w_row = jnp.asarray(w_off_r, jnp.float32)
-            ddiag = jnp.asarray(w_diag_r, jnp.float32).reshape(n, 1)
-            kops = (self._wire_k_vec(state.comm),) if wk else ()
-            adds = (
-                self._ring_slot0(state.comm, wire_keys) if pipelined else ()
-            )
-            noises = (
-                (self._dp_noise_full(state.comm, cfg.n_nodes),) if dp else ()
-            )
-
-            if cfg.algorithm == "dsgd":
-                outs = sm_dsgd(
-                    self._f32(state.params), grads, state.comm["recon"],
-                    state.comm["residual"],
-                    *[state.comm[k] for k in nbr_keys],
-                    *adds, w_row, ddiag, *kops, alpha32, *noises,
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
+                alpha32 = jnp.asarray(alpha, jnp.float32)
+                w_off_r, w_diag_r, topo_comm, gate_metrics = self._round_gates(
+                    state.comm
                 )
-                mixed, nrecon, nres = outs[:3]
-                comm = {"recon": nrecon, "residual": nres, **topo_comm}
-                comm.update(zip(nbr_keys, outs[3:3 + nnbr]))
-                self._push_wire(
-                    state.comm, comm, wire_keys, outs[3 + nnbr:]
-                )
-                new_state = state._replace(
-                    step=step, params=self._st(mixed), comm=comm
-                )
-            else:
-                adds_t = (
-                    self._ring_slot0(state.comm, wire_keys_t)
+                w_row = jnp.asarray(w_off_r, jnp.float32)
+                ddiag = jnp.asarray(w_diag_r, jnp.float32).reshape(n, 1)
+                kops = (self._wire_k_vec(state.comm),) if wk else ()
+                adds = (
+                    self._ring_slot0(state.comm, wire_keys)
                     if pipelined else ()
                 )
-                if dp:
-                    noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
-                                                   tracker=True),)
-                outs = sm_dsgt(
-                    self._f32(state.params), self._f32(state.tracker),
-                    grads, self._f32(state.prev_grad),
-                    state.comm["recon"], state.comm["residual"],
-                    state.comm["recon_t"], state.comm["residual_t"],
-                    *[state.comm[k] for k in nbr_keys],
-                    *[state.comm[k] for k in nbr_keys_t],
-                    *adds, *adds_t, w_row, ddiag, *kops, alpha32, *noises,
-                )
-                (mx, mt, nrx, nsx, nrt, nst) = outs[:6]
-                comm = {"recon": nrx, "residual": nsx,
-                        "recon_t": nrt, "residual_t": nst, **topo_comm}
-                comm.update(zip(
-                    nbr_keys + nbr_keys_t, outs[6:6 + 2 * nnbr]
-                ))
-                self._push_wire(
-                    state.comm, comm, wire_keys + wire_keys_t,
-                    outs[6 + 2 * nnbr:],
-                )
-                new_state = FLState(
-                    step=step, params=self._st(mx), tracker=self._st(mt),
-                    prev_grad=self._st(grads), comm=comm,
+                noises = (
+                    (self._dp_noise_full(state.comm, cfg.n_nodes),)
+                    if dp else ()
                 )
 
-            metrics = self._metrics(
-                cfg, losses, grads, alpha, new_state, egress
+                if cfg.algorithm == "dsgd":
+                    outs = sm_dsgd(
+                        self._f32(state.params), grads, state.comm["recon"],
+                        state.comm["residual"],
+                        *[state.comm[k] for k in nbr_keys],
+                        *adds, w_row, ddiag, *kops, alpha32, *noises,
+                    )
+                    mixed, nrecon, nres = outs[:3]
+                    comm = {"recon": nrecon, "residual": nres, **topo_comm}
+                    comm.update(zip(nbr_keys, outs[3:3 + nnbr]))
+                    self._push_wire(
+                        state.comm, comm, wire_keys, outs[3 + nnbr:]
+                    )
+                    new_state = state._replace(
+                        step=step, params=self._st(mixed), comm=comm
+                    )
+                else:
+                    adds_t = (
+                        self._ring_slot0(state.comm, wire_keys_t)
+                        if pipelined else ()
+                    )
+                    if dp:
+                        noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
+                                                       tracker=True),)
+                    outs = sm_dsgt(
+                        self._f32(state.params), self._f32(state.tracker),
+                        grads, self._f32(state.prev_grad),
+                        state.comm["recon"], state.comm["residual"],
+                        state.comm["recon_t"], state.comm["residual_t"],
+                        *[state.comm[k] for k in nbr_keys],
+                        *[state.comm[k] for k in nbr_keys_t],
+                        *adds, *adds_t, w_row, ddiag, *kops, alpha32, *noises,
+                    )
+                    (mx, mt, nrx, nsx, nrt, nst) = outs[:6]
+                    comm = {"recon": nrx, "residual": nsx,
+                            "recon_t": nrt, "residual_t": nst, **topo_comm}
+                    comm.update(zip(
+                        nbr_keys + nbr_keys_t, outs[6:6 + 2 * nnbr]
+                    ))
+                    self._push_wire(
+                        state.comm, comm, wire_keys + wire_keys_t,
+                        outs[6 + 2 * nnbr:],
+                    )
+                    new_state = FLState(
+                        step=step, params=self._st(mx), tracker=self._st(mt),
+                        prev_grad=self._st(grads), comm=comm,
+                    )
+
+            metrics = self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, gate_metrics
             )
-            metrics.update(gate_metrics)
             if wk:
-                metrics["wire_bytes_effective"] = self._wire_k_bytes(
-                    kops[0], wires=2 if cfg.algorithm == "dsgt" else 1
-                )
+                with jax.named_scope(STAGE_METRICS):
+                    metrics["wire_bytes_effective"] = self._wire_k_bytes(
+                        kops[0], wires=2 if cfg.algorithm == "dsgt" else 1
+                    )
             return new_state, metrics
 
         return None, comm_step
@@ -3385,40 +3433,41 @@ class ShardedFusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
-            alpha32 = jnp.asarray(alpha, jnp.float32)
-            priv_comm = self._priv_comm(state.comm)
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
+                alpha32 = jnp.asarray(alpha, jnp.float32)
+                priv_comm = self._priv_comm(state.comm)
 
-            if cfg.algorithm == "dsgd":
-                mixed, nrecon, nres, new_mix = sm_dsgd(
-                    self._f32(state.params), grads, state.comm["recon"],
-                    state.comm["residual"], state.comm["mix_recon"],
-                    alpha32, w_diag, w_off, *priv_operands(state.comm, 1),
-                )
-                new_state = state._replace(
-                    step=step, params=self._st(mixed),
-                    comm={"recon": nrecon, "residual": nres,
-                          "mix_recon": new_mix, **priv_comm},
-                )
-            else:
-                (mx, mt, nrx, nsx, nmrx, nrt, nst, nmrt) = sm_dsgt(
-                    self._f32(state.params), self._f32(state.tracker),
-                    grads, self._f32(state.prev_grad),
-                    state.comm["recon"], state.comm["residual"],
-                    state.comm["mix_recon"], state.comm["recon_t"],
-                    state.comm["residual_t"], state.comm["mix_recon_t"],
-                    alpha32, w_diag, w_off, *priv_operands(state.comm, 2),
-                )
-                new_state = FLState(
-                    step=step, params=self._st(mx), tracker=self._st(mt),
-                    prev_grad=self._st(grads),
-                    comm={"recon": nrx, "residual": nsx, "mix_recon": nmrx,
-                          "recon_t": nrt, "residual_t": nst,
-                          "mix_recon_t": nmrt, **priv_comm},
-                )
+                if cfg.algorithm == "dsgd":
+                    mixed, nrecon, nres, new_mix = sm_dsgd(
+                        self._f32(state.params), grads, state.comm["recon"],
+                        state.comm["residual"], state.comm["mix_recon"],
+                        alpha32, w_diag, w_off, *priv_operands(state.comm, 1),
+                    )
+                    new_state = state._replace(
+                        step=step, params=self._st(mixed),
+                        comm={"recon": nrecon, "residual": nres,
+                              "mix_recon": new_mix, **priv_comm},
+                    )
+                else:
+                    (mx, mt, nrx, nsx, nmrx, nrt, nst, nmrt) = sm_dsgt(
+                        self._f32(state.params), self._f32(state.tracker),
+                        grads, self._f32(state.prev_grad),
+                        state.comm["recon"], state.comm["residual"],
+                        state.comm["mix_recon"], state.comm["recon_t"],
+                        state.comm["residual_t"], state.comm["mix_recon_t"],
+                        alpha32, w_diag, w_off, *priv_operands(state.comm, 2),
+                    )
+                    new_state = FLState(
+                        step=step, params=self._st(mx), tracker=self._st(mt),
+                        prev_grad=self._st(grads),
+                        comm={"recon": nrx, "residual": nsx, "mix_recon": nmrx,
+                              "recon_t": nrt, "residual_t": nst,
+                              "mix_recon_t": nmrt, **priv_comm},
+                    )
 
-            return new_state, self._metrics(
-                cfg, losses, grads, alpha, new_state, egress
+            return new_state, self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, {}
             )
 
         return comm_step
@@ -3554,51 +3603,57 @@ class ShardedFusedEngine(_FusedBase):
             step = state.step + 1
             alpha = schedule(step)
             losses, grads = eval_grads(state.params, batch)
-            grads = grads.astype(jnp.float32)
-            alpha32 = jnp.asarray(alpha, jnp.float32)
-            priv_comm = self._priv_comm(state.comm)
-            noises = (
-                (self._dp_noise_full(state.comm, cfg.n_nodes),) if dp else ()
-            )
-
-            if cfg.algorithm == "dsgd":
-                outs = sm_dsgd(
-                    self._f32(state.params), grads, state.comm["recon"],
-                    state.comm["residual"], state.comm["mix_recon"],
-                    stale["mix"], alpha32, w_diag, *noises,
-                )
-                mixed, nrecon, nres, new_mix = outs[:4]
-                comm = {"recon": nrecon, "residual": nres,
-                        "mix_recon": new_mix, **priv_comm}
-                self._push_wire(state.comm, comm, wire_keys, outs[4:])
-                new_state = state._replace(
-                    step=step, params=self._st(mixed), comm=comm
-                )
-            else:
-                if dp:
-                    noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
-                                                   tracker=True),)
-                outs = sm_dsgt(
-                    self._f32(state.params), self._f32(state.tracker),
-                    grads, self._f32(state.prev_grad),
-                    state.comm["recon"], state.comm["residual"],
-                    state.comm["mix_recon"], state.comm["recon_t"],
-                    state.comm["residual_t"], state.comm["mix_recon_t"],
-                    stale["mix"], stale["mix_t"], alpha32, w_diag, *noises,
-                )
-                (mx, mt, nrx, nsx, nmrx, nrt, nst, nmrt) = outs[:8]
-                comm = {"recon": nrx, "residual": nsx, "mix_recon": nmrx,
-                        "recon_t": nrt, "residual_t": nst,
-                        "mix_recon_t": nmrt, **priv_comm}
-                self._push_wire(state.comm, comm, wire_keys, outs[8:8 + nw])
-                self._push_wire(state.comm, comm, wire_keys_t, outs[8 + nw:])
-                new_state = FLState(
-                    step=step, params=self._st(mx), tracker=self._st(mt),
-                    prev_grad=self._st(grads), comm=comm,
+            with jax.named_scope(STAGE_WIRE):
+                grads = grads.astype(jnp.float32)
+                alpha32 = jnp.asarray(alpha, jnp.float32)
+                priv_comm = self._priv_comm(state.comm)
+                noises = (
+                    (self._dp_noise_full(state.comm, cfg.n_nodes),)
+                    if dp else ()
                 )
 
-            return new_state, self._metrics(
-                cfg, losses, grads, alpha, new_state, egress
+                if cfg.algorithm == "dsgd":
+                    outs = sm_dsgd(
+                        self._f32(state.params), grads, state.comm["recon"],
+                        state.comm["residual"], state.comm["mix_recon"],
+                        stale["mix"], alpha32, w_diag, *noises,
+                    )
+                    mixed, nrecon, nres, new_mix = outs[:4]
+                    comm = {"recon": nrecon, "residual": nres,
+                            "mix_recon": new_mix, **priv_comm}
+                    self._push_wire(state.comm, comm, wire_keys, outs[4:])
+                    new_state = state._replace(
+                        step=step, params=self._st(mixed), comm=comm
+                    )
+                else:
+                    if dp:
+                        noises += (self._dp_noise_full(state.comm, cfg.n_nodes,
+                                                       tracker=True),)
+                    outs = sm_dsgt(
+                        self._f32(state.params), self._f32(state.tracker),
+                        grads, self._f32(state.prev_grad),
+                        state.comm["recon"], state.comm["residual"],
+                        state.comm["mix_recon"], state.comm["recon_t"],
+                        state.comm["residual_t"], state.comm["mix_recon_t"],
+                        stale["mix"], stale["mix_t"], alpha32, w_diag, *noises,
+                    )
+                    (mx, mt, nrx, nsx, nmrx, nrt, nst, nmrt) = outs[:8]
+                    comm = {"recon": nrx, "residual": nsx, "mix_recon": nmrx,
+                            "recon_t": nrt, "residual_t": nst,
+                            "mix_recon_t": nmrt, **priv_comm}
+                    self._push_wire(
+                        state.comm, comm, wire_keys, outs[8:8 + nw]
+                    )
+                    self._push_wire(
+                        state.comm, comm, wire_keys_t, outs[8 + nw:]
+                    )
+                    new_state = FLState(
+                        step=step, params=self._st(mx), tracker=self._st(mt),
+                        prev_grad=self._st(grads), comm=comm,
+                    )
+
+            return new_state, self._round_metrics(
+                cfg, losses, grads, alpha, new_state, egress, {}
             )
 
         return ingest, comm_step

@@ -270,8 +270,16 @@ def make_fl_round(
             "pass the mixing backend inside the engine, not as gossip_fn"
         )
 
+    from repro.core.engine import STAGE_LOCAL, resolve_schedule
+
     grad_fn = jax.vmap(jax.value_and_grad(loss_fn))
-    eval_grads = engine.make_eval_grads(grad_fn)
+    engine_eval_grads = engine.make_eval_grads(grad_fn)
+
+    def eval_grads(params: PyTree, batch: PyTree):
+        # every step's forward and backward, the comm step's included,
+        # runs under the local-step stage
+        with jax.named_scope(STAGE_LOCAL):
+            return engine_eval_grads(params, batch)
 
     def local_step(state: FLState, batch: PyTree,
                    mask=None) -> Tuple[FLState, jnp.ndarray]:
@@ -289,8 +297,6 @@ def make_fl_round(
     # (ingest the in-flight collective BEFORE the scan, mix one-round
     # stale). The schedule is fixed at engine construction because it is
     # part of the comm-state contract (repro.core.engine.RoundSchedule).
-    from repro.core.engine import resolve_schedule
-
     round_schedule = resolve_schedule(getattr(engine, "round_schedule", None))
     return round_schedule.build_round(engine, eval_grads, schedule, cfg,
                                       local_step)

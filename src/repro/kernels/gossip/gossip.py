@@ -399,6 +399,7 @@ def gossip_mix_pallas(
         out_shape=[buf, buf, buf, tl.scales],
         input_output_aliases={1: 1, 2: 2},
         interpret=interpret,
+        name="gossip_mix",
     )(x, recon, res, w_off, w_self.reshape(n, 1))
     return mixed, nrecon, nres, tl.scales_2d(s3)
 
@@ -440,6 +441,7 @@ def fused_round_pallas(
         out_shape=[buf, buf, buf, tl.scales],
         input_output_aliases={2: 1, 3: 2},
         interpret=interpret,
+        name="gossip_fused_round",
     )(x, g, recon, res, w_off, w_self.reshape(n, 1), _alpha(alpha))
     return mixed, nrecon, nres, tl.scales_2d(s3)
 
@@ -487,6 +489,7 @@ def fused_round_gt_pallas(
         out_shape=[buf] * 6 + [tl.scales, tl.scales],
         input_output_aliases={4: 2, 5: 3, 6: 4, 7: 5},
         interpret=interpret,
+        name="gossip_fused_round_gt",
     )(x, t, g, g_prev, recon_x, res_x, recon_t, res_t, w_off,
       w_self.reshape(n, 1), _alpha(alpha))
     return mx, mt, nrx, nsx, nrt, nst, tl.scales_2d(scx), tl.scales_2d(sct)
@@ -575,6 +578,7 @@ def wire_stage_pallas(
                    buf, buf],
         input_output_aliases={0: 0, 2: 3, 3: 4},
         interpret=interpret,
+        name="gossip_wire_stage",
     )(x, g, recon, res, _alpha(alpha))
     return h, q, tl.scales_2d(s3), nrecon, nres
 
@@ -620,6 +624,7 @@ def wire_stage_gt_pallas(
                    buf],
         input_output_aliases={0: 0, 1: 1, 4: 4, 5: 5, 6: 8, 7: 9},
         interpret=interpret,
+        name="gossip_wire_stage_gt",
     )(x, t, g, g_prev, recon_x, res_x, recon_t, res_t, _alpha(alpha))
     return (h, th, qx, tl.scales_2d(scx), nrx, nsx, qt, tl.scales_2d(sct),
             nrt, nst)
@@ -774,6 +779,7 @@ def wire_stage_compact_pallas(
         out_shape=[buf, q_shape, idx_shape, tl.scales, buf, buf],
         input_output_aliases={0: 0, 2: 4, 3: 5},
         interpret=interpret,
+        name="gossip_wire_stage_compact",
     )(x, g, recon, res, _alpha(alpha))
     return h, q, idx, tl.scales_2d(s3), nrecon, nres
 
@@ -827,6 +833,7 @@ def wire_stage_gt_compact_pallas(
                    q_shape, idx_shape, tl.scales, buf, buf],
         input_output_aliases={0: 0, 1: 1, 4: 5, 5: 6, 6: 10, 7: 11},
         interpret=interpret,
+        name="gossip_wire_stage_gt_compact",
     )(x, t, g, g_prev, recon_x, res_x, recon_t, res_t, _alpha(alpha))
     return (h, th, qx, px, tl.scales_2d(scx), nrx, nsx, qt, pt,
             tl.scales_2d(sct), nrt, nst)
