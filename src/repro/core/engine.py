@@ -473,8 +473,10 @@ def _check_flat_params(cfg: FLConfig, params: PyTree, name: str) -> None:
 
 def _make_flat_eval_grads(layout: FlatLayout, grad_fn):
     def eval_grads(params: jnp.ndarray, batch: PyTree):
-        # The tree view exists only inside this call; XLA lowers the
-        # unpack/pack pair to slices/concat and fuses them away.
+        # The tree view exists only inside this call. The conversion
+        # copies (the buffer and the leaves tile differently on a TPU);
+        # unpack/pack_like go through 128-lane rows, so the copies are
+        # plain passes, not a relayout loop per node (core/packing.py).
         losses, grads = grad_fn(unpack(params, layout), batch)
         return losses, pack_like(grads, layout)
 

@@ -30,7 +30,7 @@ axis):
 
 **Flat-buffer engine.** All backends route through ``core.packing``: the
 node-stacked pytree is collapsed into a single ``(nodes, total_params)``
-buffer (pack/unpack are reshape+concat/slice, fused away by XLA), turning
+buffer (pack/unpack are reshape+concat/slice copies), turning
 a round from O(n_leaves) collectives/matmuls into O(1). The historical
 leaf-by-leaf implementations are kept as ``*_per_leaf`` references -- the
 equivalence oracles and the benchmark baseline (``benchmarks/

@@ -150,5 +150,36 @@ def test_round_stages_in_compiled_hlo(case, request):
     assert got["permute_stages"] == want_permute, (case, got)
 
 
+@pytest.mark.parametrize("engine", ["flat", "fused"])
+def test_flat_conversion_scopes_in_local_step(engine):
+    """The flat engines' conversion between the packed state and the leaf
+    view around ``eval_grads`` carries ``flat_unpack`` and ``flat_pack``
+    in its compiled ``op_name``s, always inside ``fl_local``."""
+    n = 4
+    rng = np.random.default_rng(0)
+    # 128-aligned leaves: the conversion takes the row path
+    params = {"w1": jnp.asarray(rng.normal(size=(n, 6, 64)), jnp.float32),
+              "w2": jnp.asarray(rng.normal(size=(n, 64, 2)), jnp.float32)}
+    batches = {"x": jnp.ones((Q, n, 4, 6)), "y": jnp.ones((Q, n, 4, 2))}
+    w = np.full((n, n), 1.0 / n)
+    if engine == "flat":
+        eng, state = FlatEngine.simulated(w, params)
+    else:
+        eng, state = FusedEngine.simulated(w, params, scale_chunk=128)
+    assert eng.layout.row_columns == eng.layout.used
+    cfg = FLConfig(algorithm="dsgd", q=Q, n_nodes=n)
+    rf = make_fl_round(_loss, None, constant(0.05), cfg, engine=eng)
+    text = jax.jit(rf).lower(
+        init_fl_state(cfg, state, engine=eng), batches).compile().as_text()
+    seen = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        parts = path.split("/")
+        for scope in ("flat_pack", "flat_unpack"):
+            if scope in parts:
+                seen.add(scope)
+                assert "fl_local" in parts[:parts.index(scope)], path
+    assert seen == {"flat_pack", "flat_unpack"}
+
+
 if __name__ == "__main__":
     print(json.dumps(_sharded_summaries(sys.argv[1:])))

@@ -10,7 +10,10 @@ much scoped VMEM); these compiles can. Widths are the main path's:
   parameters padded to 1,536 columns;
 * ``wire_stage`` / ``wire_stage_gt`` -- one site's row of the sharded
   engine at smollm-360m's published widths, the packed parameter count
-  padded to the 512-column scale chunk.
+  padded to the 512-column scale chunk;
+* ``pack_like`` / ``unpack`` -- the conversion between two smollm-360m
+  sites' parameter leaves and their packed bfloat16 ``(2, total)`` state,
+  which must lower without a per-node relayout loop.
 
 The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the worker that runs this
@@ -25,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.gossip import gossip as G
+from test_packing import _column_pack_like, _column_unpack
 
 CHUNK = 512
 
@@ -51,17 +55,23 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def smollm_width():
-    """smollm-360m's packed row: every parameter, padded to the chunk."""
+def _smollm_stacked(n, sharding=None):
+    """smollm-360m's parameter leaves stacked for ``n`` sites."""
     from repro.configs import get_config
-    from repro.core.packing import pack_layout
     from repro.models import build_model
 
     shapes = build_model(get_config("smollm-360m")).param_shapes()
-    stacked = jax.tree_util.tree_map(
-        lambda l: jax.ShapeDtypeStruct((1,) + l.shape, l.dtype), shapes)
-    return pack_layout(stacked, pad_to=CHUNK).total
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct((n,) + l.shape, l.dtype,
+                                       sharding=sharding), shapes)
+
+
+@pytest.fixture(scope="module")
+def smollm_width():
+    """smollm-360m's packed row: every parameter, padded to the chunk."""
+    from repro.core.packing import pack_layout
+
+    return pack_layout(_smollm_stacked(1), pad_to=CHUNK).total
 
 
 def _compile_has_kernel(fn, *args) -> bool:
@@ -100,3 +110,33 @@ def test_wire_stage_compiles_for_v5e_smollm_row(one_chip, smollm_width, dsgt):
         fn = functools.partial(G.wire_stage_pallas, scale_chunk=CHUNK)
         args = [buf] * 4 + [alpha]
     assert _compile_has_kernel(fn, *args)
+
+
+def _has_relayout_loop(fn, arg) -> bool:
+    """Whether the compiled text holds a per-node relayout loop (the
+    compiler names its body ``wide.body``)."""
+    return "wide.body" in jax.jit(fn).lower(arg).compile().as_text()
+
+
+@pytest.mark.parametrize("direction", ["pack", "unpack"])
+def test_flat_conversion_has_no_relayout_loop_for_v5e(one_chip, direction):
+    """Two smollm-360m sites, bfloat16 state, 512-column chunks. The
+    column oracle is compiled without the attention leaves, whose loops
+    take over a minute to compile; the MLP, norm and embedding leaves at
+    their published widths show the loop."""
+    from repro.core.packing import pack_layout, pack_like, unpack
+
+    full = _smollm_stacked(2, one_chip)
+    part = dict(full, blocks={k: v for k, v in full["blocks"].items()
+                              if k != "attn"})
+    for tree in (part, full):
+        layout = pack_layout(tree, pad_to=CHUNK, storage_dtype=jnp.bfloat16)
+        if direction == "pack":
+            fns, arg = (_column_pack_like, pack_like), tree
+        else:
+            fns = (_column_unpack, unpack)
+            arg = _sds(one_chip, (2, layout.total), jnp.bfloat16)
+        oracle, new = (functools.partial(f, layout=layout) for f in fns)
+        if tree is part:
+            assert _has_relayout_loop(oracle, arg)
+        assert not _has_relayout_loop(new, arg)
