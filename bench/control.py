@@ -97,12 +97,7 @@ def main(argv=None) -> int:
         prog = None
         if args.program:
             fed = harness.Federation(cell, seed, devices)
-            prog = {"losses": []}
-            for r in range(harness.SETUP_ROUNDS):
-                prog["losses"].append(fed.step(r)["loss"])
-                if r == 0:
-                    prog["grad"] = fed.norms("grad")
-            prog["change"] = fed.norms("change")
+            prog = fed.first_rounds()
             w, pool = fed.w, fed.pool
             del fed
             gc.collect()

@@ -6,7 +6,8 @@ plain reference ``configs/<config>.py``), the traffic mix
 (``traffic/<traffic>.json``), the program adapter (``systems/<system>.py``),
 the engine adapter (``engines/<engine>.py``), the cell's limits
 (``limits/<workload>.json``) and each per-layer metric's reader
-(``metrics/<metric>.py``). ``run`` builds the cell from the seed, drives its
+(``metrics/<metric>.py``; one that declares ``SCOPE`` reads the device time
+of that named scope of the program). ``run`` builds the cell from the seed, drives its
 first rounds through the window's own call and feed, measures the window,
 and compares those first rounds with the reference.
 
@@ -41,6 +42,7 @@ if str(BENCH) not in sys.path:
 
 import correctness  # noqa: E402
 import reference  # noqa: E402
+import stages  # noqa: E402
 import trace_reduce  # noqa: E402
 from traffic import jax_seed, make_pool, mixing_weights, round_batch  # noqa: E402
 
@@ -77,6 +79,12 @@ class Cell:
     limits: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    readers: Dict[str, ModuleType]  # per-layer metric name -> its reader
+
+    def scopes(self) -> List[str]:
+        """The named scopes the cell's per-layer readers declare."""
+        return sorted({r.SCOPE for r in self.readers.values()
+                       if hasattr(r, "SCOPE")})
 
 
 def _named(entries, name, what):
@@ -99,6 +107,7 @@ def resolve(workload: str, smoke: bool = False, root: Path = ROOT) -> Cell:
                                      "configuration")["file"])
     bench = root / manifest["paths"][0]
     traffic = load_json(bench / "traffic" / f"{wl['traffic']}.json")
+    per_layer = [m for m in manifest["per_layer"] if _applies(m, workload)]
     if smoke:
         config.update(config.get("smoke", {}))
         traffic.update(traffic.get("smoke", {}))
@@ -109,7 +118,9 @@ def resolve(workload: str, smoke: bool = False, root: Path = ROOT) -> Cell:
         engine=load_module(bench / "engines" / f"{traffic['engine']}.py"),
         limits=load_json(bench / "limits" / f"{workload}.json"),
         end_to_end=[m for m in manifest["end_to_end"] if _applies(m, workload)],
-        per_layer=[m for m in manifest["per_layer"] if _applies(m, workload)],
+        per_layer=per_layer,
+        readers={m["name"]: load_module(bench / "metrics" / f"{m['name']}.py")
+                 for m in per_layer},
     )
 
 
@@ -171,8 +182,11 @@ class Federation:
         alpha = jnp.float32(t["alpha"])  # the first step's
 
         def leaf_sq(flat):
-            return jnp.stack([jnp.sum(jnp.square(l.astype(jnp.float32)))
-                              for l in jax.tree_util.tree_leaves(view(flat))])
+            """Each leaf's sum of squares at each site: (leaves, sites)."""
+            return jnp.stack([
+                jnp.sum(jnp.square(l.astype(jnp.float32)),
+                        axis=tuple(range(1, l.ndim)))
+                for l in jax.tree_util.tree_leaves(view(flat))])
 
         if t["algorithm"] == "dsgt":
             grad = lambda s, th: s.prev_grad.astype(jnp.float32)  # noqa: E731
@@ -186,10 +200,25 @@ class Federation:
         self._change_sq = jax.jit(lambda s, th: leaf_sq(
             s.params.astype(jnp.float32) - th.astype(jnp.float32)[None]))
 
-    def norms(self, which: str) -> Dict[str, float]:
+    def norms(self, which: str) -> List[Dict[str, float]]:
+        """Each site's per-leaf norms of the first gradient (``grad``) or of
+        the change from the first weights (``change``)."""
         fn = self._grad_sq if which == "grad" else self._change_sq
         sq = np.asarray(fn(self.state, self.theta0), np.float64)
-        return {k: float(np.sqrt(v)) for k, v in zip(self.names, sq)}
+        return [reference.site_norms(self.names, col) for col in sq.T]
+
+    def first_rounds(self) -> dict:
+        """Drives the first ``SETUP_ROUNDS`` rounds through the window's own
+        call and feed; returns what the reference is compared on: each
+        round's loss, each site's first gradient and its change."""
+        prog = {"losses": []}
+        for r in range(SETUP_ROUNDS):
+            prog["losses"].append(self.step(r)["loss"])
+            if r == 0:
+                prog["grad_sites"] = self.norms("grad")
+        prog["change_sites"] = self.norms("change")
+        self.theta0 = None
+        return prog
 
     def step(self, r: int, spans: bool = False) -> Dict[str, float]:
         """One round with the trainer's host work; returns its metrics."""
@@ -199,6 +228,7 @@ class Federation:
             batch = round_batch(self.pool, self.q, r)
         with ann("bench_dispatch"):
             self.state, m = self.round_fn(self.state, batch)
+        self.metrics = m
         with ann("bench_fetch"):
             vals = {k: float(m[k]) for k in FETCH}
             vals["iteration"] = int(self.state.step)
@@ -231,13 +261,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool = False,
         if "backend_compile" in name else None)
 
     fed = Federation(cell, seed, devices, fault)
-    prog = {"losses": []}
-    for r in range(SETUP_ROUNDS):
-        prog["losses"].append(fed.step(r)["loss"])
-        if r == 0:
-            prog["grad"] = fed.norms("grad")
-    prog["change"] = fed.norms("change")
-    fed.theta0 = None
+    prog = fed.first_rounds()
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f} s; first losses {prog['losses']}")
 
@@ -264,14 +288,19 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool = False,
         f"{in_window} compiles in the window; last round {vals}")
     stats = [d.memory_stats() or {} for d in devices]
     peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
-    kernels = []
+    # the last window round's metrics dict, fetched once the window closed
+    round_metrics = {k: float(v) if np.ndim(v) == 0 else np.asarray(v).tolist()
+                     for k, v in fed.metrics.items()}
+    kernels, scopes_of = [], None
     if trace:
         sds = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=a.sharding), fed.state)
         batch = round_batch(fed.pool, fed.q, 0)
-        kernels = wire_kernel_names(
-            fed.round_fn.lower(sds, batch).compile().as_text())
+        text = fed.round_fn.lower(sds, batch).compile().as_text()
+        kernels = wire_kernel_names(text)
+        scopes_of = stages.scope_map(text, cell.scopes())
+        del text
     w, pool, q, total = fed.w, fed.pool, fed.q, fed.total
     del fed
     gc.collect()
@@ -296,11 +325,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool = False,
         "setup_s": setup_s,
         "round_ms": 1e3 * window_s / rounds,
         "flat_total": total,
+        "round_metrics": round_metrics,
         "checks": checks,
     }
     if trace:
         tr = trace_reduce.reduce(
-            trace_reduce.load(str(trace_dir), [d.id for d in devices]), kernels)
+            trace_reduce.load(str(trace_dir), [d.id for d in devices]), kernels,
+            scopes_of=scopes_of)
         shutil.rmtree(trace_dir, ignore_errors=True)
         out["trace"] = tr
         log(json.dumps({"kernels": kernels, "trace": tr}))
@@ -329,14 +360,14 @@ def result_line(cell: Cell, out: dict, peak: dict) -> dict:
         n = int(t["graph"]["n"])
         ctx = {
             "trace": tr, "rounds": out["rounds"], "chips": cell.chips,
-            "peaks": peak,
+            "peaks": peak, "round_metrics": out["round_metrics"],
             "flops_per_round": cell.model.train_flops(cell.config, t)
             * n * int(t["q"]) * int(t["batch"]),
             "wire_bytes_per_chip": cell.engine.wire_bytes(
                 t, out["flat_total"], n // cell.chips),
         }
         for m in cell.per_layer:
-            v = load_module(BENCH / "metrics" / f"{m['name']}.py").read(ctx)
+            v = cell.readers[m["name"]].read(ctx)
             if v is not None:
                 line["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
         chips = tr.get("chips", [])

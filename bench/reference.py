@@ -30,7 +30,7 @@ import numpy as np
 
 from traffic import step_size
 
-__all__ = ["FlatView", "run_reference", "leaf_norms"]
+__all__ = ["FlatView", "run_reference", "site_norms"]
 
 
 class FlatView:
@@ -61,10 +61,10 @@ class FlatView:
                           for o, n in zip(self.offsets, self.sizes)])
 
 
-def leaf_norms(names, sq_per_site) -> Dict[str, float]:
-    """Per-leaf norms over all sites from per-site sums of squares."""
-    total = np.sum([np.asarray(s, np.float64) for s in sq_per_site], axis=0)
-    return {k: float(np.sqrt(v)) for k, v in zip(names, total)}
+def site_norms(names, sq) -> Dict[str, float]:
+    """One site's per-leaf norms from its per-leaf sums of squares."""
+    return {k: float(np.sqrt(v)) for k, v in
+            zip(names, np.asarray(sq, np.float64))}
 
 
 def _quantize(p, chunk):
@@ -86,10 +86,12 @@ def run_reference(model, config: dict, traffic: dict, params, w: np.ndarray,
     the program, for the control runs. ``devices``: site i lives on
     ``devices[i % len(devices)]``.
 
-    Returns ``losses`` (the comm step's mean loss per round), ``grad`` (per
-    leaf: norm over sites of the first gradient as the optimizer state
-    holds it after round 1) and ``change`` (per leaf: norm over sites of
-    the parameters' change after the last round)."""
+    Returns ``losses`` (the comm step's mean loss per round),
+    ``site_losses`` (each round's comm-step loss of every site, whose mean
+    is ``losses``), ``grad_sites`` (each site's per-leaf norms of the
+    first gradient as the optimizer state holds it after round 1) and
+    ``change_sites`` (each site's per-leaf norms of the parameters' change
+    after the last round)."""
     n, q = int(traffic["graph"]["n"]), int(traffic["q"])
     alpha = jnp.float32(traffic["alpha"])  # the first step's
     chunk = int(traffic["scale_chunk"])
@@ -157,7 +159,7 @@ def run_reference(model, config: dict, traffic: dict, params, w: np.ndarray,
             v[i] = None
         return out
 
-    out = {"losses": []}
+    out = {"losses": [], "site_losses": []}
     for r, batch in enumerate(batches):
         for s in range(q - 1):
             a = f32(step_size(traffic, r * q + s + 1))
@@ -180,14 +182,17 @@ def run_reference(model, config: dict, traffic: dict, params, w: np.ndarray,
         x = exchange(h, recon, res)
         if dsgt:
             t = exchange(th, recon_t, res_t)
-        out["losses"].append(float(np.mean([float(v) for v in losses])))
+        site = [float(v) for v in losses]
+        out["site_losses"].append(site)
+        out["losses"].append(float(np.mean(site)))
         if r == 0:
             if dsgt:
                 sq = [leaf_sq(v.astype(f32)) for v in gp]
             else:
                 sq = [grad_sq(jax.device_put(theta0, dev[i]), recon[i], res[i])
                       for i in range(n)]
-            out["grad"] = leaf_norms(view.names, sq)
-    out["change"] = leaf_norms(view.names, [
-        change_sq(x[i], jax.device_put(theta0, dev[i])) for i in range(n)])
+            out["grad_sites"] = [site_norms(view.names, v) for v in sq]
+    out["change_sites"] = [
+        site_norms(view.names, change_sq(x[i], jax.device_put(theta0, dev[i])))
+        for i in range(n)]
     return out

@@ -15,6 +15,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+import correctness  # noqa: E402
 import harness  # noqa: E402
 
 SEED = 2**31 + 12345  # larger than 32 signed bits hold
@@ -34,7 +35,7 @@ def _check_line(cell, out):
     assert list(line) == ["correct", "attempted", "failed", "metrics",
                           "device", "checks"]
     assert set(line["metrics"]) == {m["name"] for m in cell.end_to_end}
-    assert set(line["checks"]) == {"loss_gap", "grad_gap", "change_gap"}
+    assert set(line["checks"]) == set(cell.limits)
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(
         line["device"])
     json.dumps(line)
@@ -51,6 +52,10 @@ def test_one_chip_cell_smoke(workload):
     line = _check_line(cell, out)
     assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
     assert out["setup_s"] > 0 and out["round_ms"] > 0
+    # the last window round's metrics, fetched once after the window
+    assert set(harness.FETCH) <= set(out["round_metrics"])
+    assert all(isinstance(out["round_metrics"][k], float)
+               for k in harness.FETCH)
 
 
 def test_run_refuses_a_cpu():
@@ -80,6 +85,25 @@ def test_manifest_resolves_every_cell():
     for w in manifest["workloads"]:
         cell = harness.resolve(w["name"])
         assert cell.per_layer and cell.end_to_end
-        assert set(cell.limits) == {"loss_gap", "grad_gap", "change_gap"}
+        assert {"loss_gap", "grad_gap", "change_gap"} <= set(cell.limits)
+        assert set(cell.limits) <= set(correctness.NUMBERS)
     for c in manifest["configs"]:
         assert c["file"].startswith(manifest["paths"][0] + "/")
+
+
+def test_reader_sees_the_round_metrics():
+    """A per-layer reader finds the last window round's metrics dict in its
+    context, so a configuration's own counters need no harness edit."""
+    import types
+
+    cell = harness.resolve(ONE_CHIP[0])
+    probe = types.ModuleType("probe")
+    probe.read = lambda ctx: ctx["round_metrics"]["consensus_err"]
+    cell.readers = {"probe": probe}
+    cell.per_layer = [{"name": "probe", "unit": "1"}]
+    out = {"correct": True, "attempted": 2, "failed": 0, "rounds": 2,
+           "device": {}, "checks": {}, "flat_total": 1536,
+           "round_metrics": {"loss": 0.5, "consensus_err": 0.25},
+           "trace": {"window_s": 1.0}}
+    line = harness.result_line(cell, out, harness.peaks("TPU v5 lite"))
+    assert line["metrics"] == {"probe": {"value": 0.25, "unit": "1"}}

@@ -142,3 +142,63 @@ def test_recorded_stage_trace():
     assert {"fl_local", "fl_wire", "fl_metrics"} <= set(got)
     assert max(got, key=got.get) == "fl_local"
     assert all(rec["stages"][k] == "fl_wire" for k in rec["kernels"])
+
+
+def test_scope_map_is_inclusive_and_inherits():
+    got = st.scope_map(_HLO, ["fl_wire", "fl_transport", "fl_local"])
+    assert got["collective-permute.1"] == ("fl_wire", "fl_transport")
+    assert got["gossip_fused_round_gt.1"] == ("fl_wire",)
+    assert got["fusion.125"] == ("fl_local", "fl_local")
+    assert got["reduce.7"] == ()  # fl_metrics was not asked for
+    assert got["fusion.9"] == ()  # a component must match a scope whole
+    # the compiler's layout copy and its loop take their operand's scopes
+    assert got["while.206"] == ("fl_local",)
+    # the innermost stage is the last scope of the stages' map
+    assert st.stage_map(_HLO) == {
+        k: v[-1] if v else st.UNSCOPED
+        for k, v in st.scope_map(_HLO, st.STAGES).items()}
+
+
+def test_scope_seconds_credit_every_enclosing_scope_once():
+    """0-4 ms a wire op holding a transport op 1-3 ms; 5-6 ms a metrics op;
+    6-7 ms an op without scope inside nothing."""
+    ev = [["wire.1", 0, 4 * MS], ["permute.1", 1 * MS, 3 * MS],
+          ["metrics.1", 5 * MS, 6 * MS], ["copy.1", 6 * MS, 7 * MS]]
+    scopes_of = {"wire.1": ("fl_wire",),
+                 "permute.1": ("fl_wire", "fl_transport"),
+                 "metrics.1": ("fl_metrics",)}
+    got = st.scope_seconds(ev, scopes_of)
+    assert got == pytest.approx({"fl_wire": 0.004, "fl_transport": 0.002,
+                                 "fl_metrics": 0.001})
+    red = tm.reduce({"devices": {"0": ev},
+                     "host": [["bench_window", 0, 10 * MS]]}, [],
+                    scopes_of=scopes_of)
+    assert red["chips"][0]["scope_s"] == pytest.approx(got)
+
+
+def test_scope_readers_on_the_recorded_trace():
+    """The readers of the round's stages on a v5e trace of
+    ``ehr-h20-dsgt-q10``: the stage map of its compiled round as scopes."""
+    import harness
+
+    rec = json.loads((BENCH / "testdata" / "trace-stages-ehr-h20-dsgt-q10.json")
+                     .read_text())
+    scopes_of = {k: (v,) if v != st.UNSCOPED else ()
+                 for k, v in rec["stages"].items()}
+    red = tm.reduce(rec["trace"], rec["kernels"], scopes_of=scopes_of)
+    ctx = {"trace": red, "rounds": rec["rounds"]}
+
+    def read(name):
+        return harness.load_module(BENCH / "metrics" / f"{name}.py").read(ctx)
+
+    # ms a round, as read by hand from the same trace
+    assert read("local_step_ms") == pytest.approx(0.1088984)
+    assert read("wire_stage_ms") == pytest.approx(0.0017066)
+    assert read("round_metrics_ms") == pytest.approx(0.0018346)
+    assert read("transport_ms") is None  # one chip: no exchange between chips
+    lo, hi = [(s, e) for n, s, e in rec["trace"]["host"]
+              if n == "bench_window"][0]
+    (ev,) = [tm._clip(e, lo, hi) for e in rec["trace"]["devices"].values()]
+    split = st.stage_seconds(ev, rec["stages"])
+    assert read("local_step_ms") == pytest.approx(
+        1e3 * split["fl_local"] / rec["rounds"])

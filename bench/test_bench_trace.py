@@ -77,7 +77,8 @@ def test_window_without_device_ops_reads_nothing():
            "wire_bytes_per_chip": 1.0,
            "peaks": {"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}}
     for name in ("device_idle_share", "wire_kernel_ms", "wire_roofline",
-                 "mfu"):
+                 "mfu", "local_step_ms", "wire_stage_ms", "round_metrics_ms",
+                 "transport_ms"):
         assert _reader(name).read(ctx) is None
 
 
@@ -115,6 +116,12 @@ def test_counts_from_shapes():
     assert ehr.model.train_flops(ehr.config, ehr.traffic) == 6 * (42 * 32 + 32 * 2)
     # DSGT megakernel, f32 state: 8 buffers read, 6 written, 4 B each
     assert ehr.engine.wire_bytes(ehr.traffic, 1536, 20) == 20 * 1536 * 14 * 4
+    ring4 = harness.resolve("smollm360m-ring4-4chip-dsgd-q2")
+    # the sharded wire stage's compiled call for a v5e: reads f32 x, g,
+    # recon, residual; writes f32 h, recon, residual, int8 values and one
+    # f32 scale a 512-column chunk
+    assert ring4.engine.wire_bytes(ring4.traffic, total, 1) == (
+        total * (4 * 4 + 3 * 4 + 1) + total // 512 * 4)
 
 
 def test_peaks_table_is_keyed_by_device_kind():

@@ -18,7 +18,9 @@ from __future__ import annotations
 import glob
 import os
 import re
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+import stages
 
 __all__ = ["load", "union_length", "reduce"]
 
@@ -76,13 +78,16 @@ def _clip(events, lo, hi):
             if e > lo and s < hi]
 
 
-def reduce(trace: dict, kernels: Sequence[str], top: int = 10) -> dict:
+def reduce(trace: dict, kernels: Sequence[str], top: int = 10,
+           scopes_of: Optional[Dict[str, Tuple[str, ...]]] = None) -> dict:
     """Per-chip busy and kernel seconds inside the measured window, and the
     breakdown of device operations and idle gaps.
 
-    ``kernels``: the device operation names of the wire stage. A chip with
-    no operation in the window is left out; with none at all the result
-    has no ``chips``."""
+    ``kernels``: the device operation names of the wire stage.
+    ``scopes_of``: ``stages.scope_map`` of the compiled round; with it each
+    chip also has ``scope_s``, the inclusive busy seconds of each scope. A
+    chip with no operation in the window is left out; with none at all the
+    result has no ``chips``."""
     windows = [(s, e) for n, s, e in trace["host"] if n == "bench_window"]
     if len(windows) != 1:
         raise ValueError(f"expected one bench_window span, found {len(windows)}")
@@ -103,6 +108,8 @@ def reduce(trace: dict, kernels: Sequence[str], top: int = 10) -> dict:
             + ([(lo, busy[0][0])] if busy[0][0] > lo else [])
             + ([(busy[-1][1], hi)] if busy[-1][1] < hi else []),
         })
+        if scopes_of is not None:
+            chips[-1]["scope_s"] = stages.scope_seconds(ev, scopes_of)
         for n, s, e in ev:
             op_time[n] = op_time.get(n, 0.0) + (e - s) * 1e-9
     out = {"window_s": (hi - lo) * 1e-9}
