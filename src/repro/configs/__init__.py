@@ -27,6 +27,7 @@ ARCH_MODULES: Dict[str, str] = {
     "whisper-medium": "whisper_medium",
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "tinyllama-1.1b": "tinyllama_1_1b",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
     "ehr-mlp": "ehr_mlp",
 }
 

@@ -48,6 +48,22 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     shared_expert: bool = False  # llama4-style always-on expert
     router_aux_coef: float = 0.01  # load-balance auxiliary loss
+    moe_d_ff: int = 0  # routed expert width (0 => d_ff)
+    shared_d_ff: int = 0  # shared expert width (0 => d_ff)
+    # experts this device holds, ids first_expert_held.. (0 => all, through
+    # the capacity dispatch; > 0 => the dropless held-expert layer, which
+    # routes over all n_experts and computes its own experts' part)
+    n_experts_held: int = 0
+    first_expert_held: int = 0
+    score_func: str = "softmax"  # softmax | sigmoid (+ a selection bias)
+    routed_scaling: float = 1.0  # factor on the renormalised top-k weights
+    first_dense_layers: int = 0  # leading layers with a dense SwiGLU of d_ff
+
+    # multi-head latent attention (DeepSeek-V2/V3); kv_lora_rank 0 => GQA
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM / hybrid
     block_pattern: Tuple[str, ...] = ()  # e.g. ("recurrent","recurrent","attention")
@@ -82,6 +98,10 @@ class ModelConfig:
             raise ValueError("n_heads must be a multiple of n_kv_heads (GQA)")
         if self.family == "moe" and (self.n_experts < 2 or self.experts_per_token < 1):
             raise ValueError("moe family needs n_experts>=2 and experts_per_token>=1")
+        if self.first_expert_held + self.n_experts_held > self.n_experts:
+            raise ValueError("held experts must lie in 0..n_experts-1")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown score_func {self.score_func!r}")
 
     @property
     def padded_vocab(self) -> int:
@@ -89,7 +109,9 @@ class ModelConfig:
 
     @property
     def effective_pattern(self) -> Tuple[str, ...]:
-        """Per-layer block types of length n_layers."""
+        """Per-layer block types of length n_layers: ``first_dense_layers``
+        leading dense layers, then the family's kind (``mla`` / ``mla_moe``
+        with latent attention)."""
         if not self.block_pattern:
             default = {
                 "dense": "attention",
@@ -99,13 +121,20 @@ class ModelConfig:
                 "ssm": "rwkv",
                 "hybrid": "recurrent",
             }[self.family]
-            return (default,) * self.n_layers
+            lead = "attention"
+            if self.kv_lora_rank:
+                default = {"attention": "mla", "moe": "mla_moe"}[default]
+                lead = "mla"
+            n = self.first_dense_layers
+            return (lead,) * n + (default,) * (self.n_layers - n)
         reps = -(-self.n_layers // len(self.block_pattern))
         return (self.block_pattern * reps)[: self.n_layers]
 
     @property
     def is_homogeneous(self) -> bool:
-        pat = self.effective_pattern
+        """Whether the layers after the leading dense ones are all alike
+        (they are then scanned as one stacked tree)."""
+        pat = self.effective_pattern[self.first_dense_layers:]
         return all(p == pat[0] for p in pat)
 
     def param_count(self) -> int:
@@ -130,10 +159,14 @@ class ModelConfig:
                 if self.qkv_bias:
                     qkv += (self.n_heads + 2 * self.n_kv_heads) * hd
                 total += qkv + (self.n_heads * hd) * d
-                total += d + d * self.n_experts  # mlp norm + router
-                total += self.n_experts * 3 * d * self.d_ff
-                if self.shared_expert:
-                    total += 3 * d * self.d_ff
+                total += d + self._expert_layer_params()  # mlp norm + experts
+            elif kind in ("mla", "mla_moe"):
+                h, r = self.n_heads, self.kv_lora_rank
+                nope, rope, vd = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+                total += d * h * (nope + rope) + d * (r + rope) + r  # q, kv_a, kv norm
+                total += r * h * (nope + vd) + h * vd * d  # kv_b, o
+                total += d  # mlp norm
+                total += 3 * d * self.d_ff if kind == "mla" else self._expert_layer_params()
             elif kind == "rwkv":
                 n_h = d // 64
                 # r,k,v,g,o projections + data-dependent decay lora + ffn
@@ -155,13 +188,27 @@ class ModelConfig:
             total += self.n_layers * (d + 4 * d * d)
         return int(total)
 
+    def _expert_layer_params(self) -> int:
+        """Router (and its selection bias), the held experts, the shared
+        expert."""
+        d = self.d_model
+        total = d * self.n_experts + (self.n_experts if self.score_func == "sigmoid" else 0)
+        total += (self.n_experts_held or self.n_experts) * 3 * d * (self.moe_d_ff or self.d_ff)
+        if self.shared_expert:
+            total += 3 * d * (self.shared_d_ff or self.d_ff)
+        return total
+
     def active_param_count(self) -> int:
-        """Active parameters per token (MoE: only routed experts count)."""
+        """Active parameters per token (MoE: only routed experts count; of
+        a share of the experts, the k * held / n_experts a token expects)."""
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        inactive = (self.n_experts - self.experts_per_token) * 3 * d * self.d_ff
-        return int(self.param_count() - len(self.effective_pattern) * inactive)
+        held = self.n_experts_held or self.n_experts
+        active = self.experts_per_token * held / self.n_experts
+        inactive = (held - active) * 3 * d * (self.moe_d_ff or self.d_ff)
+        n_moe = sum(k in ("moe", "mla_moe") for k in self.effective_pattern)
+        return int(self.param_count() - n_moe * inactive)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -939,7 +939,12 @@ class GossipEngine(abc.ABC):
         mean loss, ||mean_i grad_i||^2, consensus error, alpha,
         comm_rounds, ``wire_bytes`` (when the engine accounts its wire,
         ``egress`` not None), the engine's state metrics, then the round's
-        topology and node gate metrics."""
+        topology and node gate metrics. ``losses`` is the per-site losses,
+        or ``(losses, counters)`` from a loss that counts: each counter's
+        mean over sites joins the dict."""
+        counters = {}
+        if isinstance(losses, tuple):
+            losses, counters = losses
         with jax.named_scope(STAGE_METRICS):
             metrics = {
                 "loss": jnp.mean(losses),
@@ -951,6 +956,7 @@ class GossipEngine(abc.ABC):
             if egress is not None:
                 metrics["wire_bytes"] = jnp.float32(egress)
             metrics.update(self._state_metrics(cfg, new_state))
+            metrics.update({k: jnp.mean(v) for k, v in counters.items()})
         metrics.update(gate_metrics)
         return metrics
 
@@ -2637,6 +2643,14 @@ class ShardedFusedEngine(_FusedBase):
             acc = jnp.zeros((rows, t), jnp.float32)
             for d, (_axis, _shift, weight) in enumerate(self.dirs):
                 recv = self._transport(wire, d, priv, stream_base)
+                if d:
+                    # dequantize one direction after the other: the TPU
+                    # compiler gives each dequant full-row temporaries
+                    # (the scales broadcast to the row, and a relayout of
+                    # it), which are then freed before the next
+                    # direction's exist -- 3.2e9 B less at the peak of a
+                    # 425M-parameter site
+                    acc, recv = jax.lax.optimization_barrier((acc, recv))
                 acc = acc + jnp.float32(weight) * self._dq_full(recv)
             return acc
         # arbitrary dense W: ONE all-gather per wire buffer (secure_agg

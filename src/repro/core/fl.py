@@ -75,7 +75,8 @@ from repro.core.mixing import GossipFn
 from repro.core.schedules import Schedule
 
 PyTree = Any
-LossFn = Callable[[PyTree, Any], jnp.ndarray]  # (params_one_node, batch_one_node) -> scalar
+# (params_one_node, batch_one_node) -> scalar, or -> (scalar, counters)
+LossFn = Callable[[PyTree, Any], Any]
 
 __all__ = [
     "FLState",
@@ -204,7 +205,9 @@ def make_fl_round(
     """Build one *communication round*: (Q-1) local steps + 1 comm step.
 
     Args:
-      loss_fn: per-node loss ``(params, batch) -> scalar`` (unstacked).
+      loss_fn: per-node loss ``(params, batch) -> scalar`` (unstacked), or
+        ``-> (scalar, counters)`` with a dict of scalar counters: the comm
+        step's counters, averaged over sites, join the round's metrics.
       gossip_fn: convenience shorthand -- a tree-level mixing backend
         (theta <- W theta); wrapped in a
         :class:`~repro.core.engine.TreeEngine`. Mutually exclusive with
@@ -272,14 +275,20 @@ def make_fl_round(
 
     from repro.core.engine import STAGE_LOCAL, resolve_schedule
 
-    grad_fn = jax.vmap(jax.value_and_grad(loss_fn))
+    def counted_loss(params, batch):
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    grad_fn = jax.vmap(jax.value_and_grad(counted_loss, has_aux=True))
     engine_eval_grads = engine.make_eval_grads(grad_fn)
 
     def eval_grads(params: PyTree, batch: PyTree):
         # every step's forward and backward, the comm step's included,
-        # runs under the local-step stage
+        # runs under the local-step stage. The per-site losses come back
+        # as they are, or as (losses, counters) where the loss counts.
         with jax.named_scope(STAGE_LOCAL):
-            return engine_eval_grads(params, batch)
+            (losses, counters), grads = engine_eval_grads(params, batch)
+        return ((losses, counters) if counters else losses), grads
 
     def local_step(state: FLState, batch: PyTree,
                    mask=None) -> Tuple[FLState, jnp.ndarray]:
@@ -290,6 +299,8 @@ def make_fl_round(
         alpha = schedule(step)
         losses, grads = eval_grads(state.params, batch)
         params = engine.local_step(state.params, grads, alpha, mask=mask)
+        if isinstance(losses, tuple):
+            losses = losses[0]
         return state._replace(step=step, params=params), jnp.mean(losses)
 
     # The engine's RoundSchedule owns the round's TIME layout: sequential

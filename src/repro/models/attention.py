@@ -1,4 +1,6 @@
-"""GQA attention: full-causal, sliding-window, bidirectional, cross, decode.
+"""GQA attention: full-causal, sliding-window, bidirectional, cross, decode;
+and multi-head latent attention (MLA), for training and for decode through a
+latent cache.
 
 Parameters use FUSED head dims -- wq: (d, H*hd), wk/wv: (d, K*hd),
 wo: (H*hd, d) -- because fused dims are divisible by the tensor-parallel
@@ -17,7 +19,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init, linear
+from repro.models.layers import apply_rope, dense_init, linear, rmsnorm, rmsnorm_init
 
 PyTree = Any
 
@@ -29,6 +31,11 @@ __all__ = [
     "cross_attn_init",
     "cross_attn_apply",
     "precompute_cross_kv",
+    "mla_init",
+    "mla_apply",
+    "init_mla_cache",
+    "mla_decode",
+    "apply_rope_interleaved",
     "NEG_INF",
 ]
 
@@ -170,7 +177,7 @@ def _sdpa_blocked(
         return None, jnp.einsum("bhst,bthd->bshd", probs, v)
 
     _, out = jax.lax.scan(per_chunk, None, (qc, jnp.arange(n_chunks)))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, hd)
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, s, h, v.shape[-1])
 
 
 def attn_apply(
@@ -331,3 +338,145 @@ def cross_attn_apply(
     k, v = kv
     out = _sdpa(q, k, v, causal=False, window=0)
     return linear(p["wo"], out.reshape(*x.shape[:-1], n_heads * head_dim), compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3, without a query LoRA)
+# ---------------------------------------------------------------------------
+#
+#   q            = W_q x                  per head: [q_nope (nope), q_pe (rope)]
+#   [c_kv, k_pe] = W_kv_a x               c_kv (rank) through its own RMSNorm
+#   [k_nope, v]  = W_kv_b c_kv            per head
+#   k_pe: one rope-wide key shared by all heads; interleaved RoPE on it and
+#   on q_pe; scores over 1/sqrt(nope + rope); out = W_o [v-weighted heads].
+#
+# Decode caches c_kv and k_pe (rank + rope values a token a layer) and
+# absorbs W_kv_b into the query and the output.
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           theta: float) -> jnp.ndarray:
+    """RoPE on consecutive pairs (x[2i], x[2i+1]) of (..., seq, heads, dim),
+    the ``rope_interleave`` layout."""
+    inv = 1.0 / theta ** (jnp.arange(0, x.shape[-1], 2, dtype=jnp.float32) / x.shape[-1])
+    ang = positions.astype(jnp.float32)[..., None, None] * inv  # (..., seq, 1, dim/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def mla_init(key, d_model: int, n_heads: int, kv_lora_rank: int, qk_nope: int,
+             qk_rope: int, v_dim: int, dtype) -> Dict:
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(kq, d_model, n_heads * (qk_nope + qk_rope), dtype),
+        "wkv_a": dense_init(ka, d_model, kv_lora_rank + qk_rope, dtype),
+        "kv_norm": rmsnorm_init(kv_lora_rank, dtype),
+        "wkv_b": dense_init(kb, kv_lora_rank, n_heads * (qk_nope + v_dim), dtype),
+        "wo": dense_init(ko, n_heads * v_dim, d_model, dtype),
+    }
+
+
+def _mla_latent(p, x, positions, *, n_heads, qk_nope, qk_rope, rope_theta, eps,
+                compute_dtype):
+    """Queries split into their no-RoPE and RoPE parts, the normed latent
+    c_kv and the shared RoPE key: (q_nope, q_pe, c_kv, k_pe (.., 1, rope))."""
+    q = _split_heads(linear(p["wq"], x, compute_dtype), n_heads, qk_nope + qk_rope)
+    q_nope, q_pe = q[..., :qk_nope], q[..., qk_nope:]
+    kv = linear(p["wkv_a"], x, compute_dtype)
+    c_kv = rmsnorm(p["kv_norm"], kv[..., :-qk_rope], eps)
+    k_pe = kv[..., None, -qk_rope:]
+    q_pe = apply_rope_interleaved(q_pe, positions, rope_theta)
+    k_pe = apply_rope_interleaved(k_pe, positions, rope_theta)
+    return q_nope, q_pe, c_kv, k_pe
+
+
+def mla_apply(
+    p: Dict,
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    *,
+    n_heads: int,
+    qk_nope: int,
+    qk_rope: int,
+    v_dim: int,
+    rope_theta: float,
+    eps: float,
+    impl: str = "ref",
+    compute_dtype=jnp.bfloat16,
+) -> jnp.ndarray:
+    """Causal latent self-attention over a full sequence (training /
+    prefill), in the ``mla`` named scope."""
+    with jax.named_scope("mla"):
+        q_nope, q_pe, c_kv, k_pe = _mla_latent(
+            p, x, positions, n_heads=n_heads, qk_nope=qk_nope, qk_rope=qk_rope,
+            rope_theta=rope_theta, eps=eps, compute_dtype=compute_dtype)
+        kv = _split_heads(linear(p["wkv_b"], c_kv, compute_dtype), n_heads, qk_nope + v_dim)
+        k_nope, v = kv[..., :qk_nope], kv[..., qk_nope:]
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)
+        if impl == "blocked":
+            out = _sdpa_blocked(q, k, v, causal=True, window=0)
+        else:
+            out = _sdpa(q, k, v, causal=True, window=0)
+        return linear(p["wo"], out.reshape(*x.shape[:-1], n_heads * v_dim), compute_dtype)
+
+
+def init_mla_cache(batch: int, length: int, kv_lora_rank: int, qk_rope: int,
+                   dtype=jnp.bfloat16) -> Dict:
+    """The latent cache: the normed c_kv and the roped shared key, per
+    position. ``pos`` is the absolute next-token position."""
+    return {
+        "ckv": jnp.zeros((batch, length, kv_lora_rank), dtype),
+        "kpe": jnp.zeros((batch, length, qk_rope), dtype),
+        "pos": jnp.zeros((), jnp.int32),
+    }
+
+
+def mla_decode(
+    p: Dict,
+    x: jnp.ndarray,
+    cache: Dict,
+    *,
+    n_heads: int,
+    qk_nope: int,
+    qk_rope: int,
+    v_dim: int,
+    rope_theta: float,
+    eps: float,
+    ring: bool = False,
+    compute_dtype=jnp.bfloat16,
+) -> Tuple[jnp.ndarray, Dict]:
+    """One-token decode: x (B,1,d) against the latent cache, with W_kv_b
+    absorbed: scores = (q_nope W_uk) . c_kv + q_pe . k_pe, and the output
+    (probs . c_kv) W_uv per head. ``ring`` as in :func:`attn_decode`."""
+    with jax.named_scope("mla"):
+        b = x.shape[0]
+        cache_len = cache["ckv"].shape[1]
+        pos = cache["pos"]
+        positions = jnp.full((b, 1), pos, jnp.int32)
+        q_nope, q_pe, c_kv, k_pe = _mla_latent(
+            p, x, positions, n_heads=n_heads, qk_nope=qk_nope, qk_rope=qk_rope,
+            rope_theta=rope_theta, eps=eps, compute_dtype=compute_dtype)
+        slot = pos % cache_len if ring else pos
+        ckv = jax.lax.dynamic_update_slice_in_dim(
+            cache["ckv"], c_kv.astype(cache["ckv"].dtype), slot, axis=1)
+        kpe = jax.lax.dynamic_update_slice_in_dim(
+            cache["kpe"], k_pe[:, :, 0].astype(cache["kpe"].dtype), slot, axis=1)
+        n_valid = jnp.minimum(pos + 1, cache_len)
+        valid = jnp.arange(cache_len) < n_valid  # (T,)
+        wkv_b = p["wkv_b"]["w"].astype(compute_dtype).reshape(-1, n_heads, qk_nope + v_dim)
+        w_uk, w_uv = wkv_b[..., :qk_nope], wkv_b[..., qk_nope:]
+        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_uk)
+        cc, kk = ckv.astype(compute_dtype), kpe.astype(compute_dtype)
+        scores = (jnp.einsum("bshc,btc->bhst", q_lat, cc).astype(jnp.float32)
+                  + jnp.einsum("bshr,btr->bhst", q_pe, kk).astype(jnp.float32))
+        scores = scores / jnp.sqrt(jnp.float32(qk_nope + qk_rope))
+        scores = jnp.where(valid[None, None, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
+        o_lat = jnp.einsum("bhst,btc->bshc", probs, cc)
+        out = jnp.einsum("bshc,chd->bshd", o_lat, w_uv)
+        out = linear(p["wo"], out.reshape(b, 1, n_heads * v_dim), compute_dtype)
+        return out, {"ckv": ckv, "kpe": kpe, "pos": pos + 1}

@@ -33,6 +33,10 @@ class ModelBundle:
     decode_fn: Callable[[PyTree, jnp.ndarray, PyTree], Tuple[jnp.ndarray, PyTree]]
     init_decode_state_fn: Callable[..., PyTree]
     param_specs_fn: Callable[[PyTree], PyTree]
+    #: ``(params, batch) -> (loss, counters)``: the loss with the layers'
+    #: counters (empty for a model without any), which ``make_fl_round``
+    #: averages over sites into the round's metrics
+    counted_loss_fn: Optional[Callable[[PyTree, Dict], Tuple[jnp.ndarray, Dict]]] = None
 
     def param_shapes(self) -> PyTree:
         return jax.eval_shape(self.init_fn, jax.random.key(0))
@@ -51,6 +55,9 @@ def _build_decoder_only(cfg: ModelConfig, impl: str, remat: bool) -> ModelBundle
     def loss_fn(params, batch):
         return tfm.lm_loss(params, cfg, batch, impl=impl, remat=remat)
 
+    def counted_loss_fn(params, batch):
+        return tfm.lm_loss(params, cfg, batch, impl=impl, remat=remat, counted=True)
+
     def prefill_fn(params, batch):
         return tfm.prefill(params, cfg, batch, impl=impl)
 
@@ -68,6 +75,7 @@ def _build_decoder_only(cfg: ModelConfig, impl: str, remat: bool) -> ModelBundle
         decode_fn=decode_fn,
         init_decode_state_fn=init_decode_state_fn,
         param_specs_fn=model_param_specs,
+        counted_loss_fn=counted_loss_fn,
     )
 
 

@@ -83,8 +83,22 @@ def init_block(key, cfg: ModelConfig, kind: str) -> Dict:
                 n_heads_layout=attn.layout_heads(cfg.n_heads, cfg.tp_head_pad),
             ),
             "ln2": rmsnorm_init(d, dt),
-            "moe": moe_mod.moe_init(k2, d, cfg.d_ff, cfg.n_experts, dt, cfg.shared_expert),
+            "moe": _moe_init(k2, cfg),
         }
+    if kind in ("mla", "mla_moe"):
+        blk = {
+            "ln1": rmsnorm_init(d, dt),
+            "attn": attn.mla_init(
+                k1, d, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                cfg.qk_rope_head_dim, cfg.v_head_dim, dt,
+            ),
+            "ln2": rmsnorm_init(d, dt),
+        }
+        if kind == "mla":
+            blk["mlp"] = swiglu_init(k2, d, cfg.d_ff, dt)
+        else:
+            blk["moe"] = _moe_init(k2, cfg)
+        return blk
     if kind == "rwkv":
         return {
             "ln1": rmsnorm_init(d, dt),
@@ -102,6 +116,41 @@ def init_block(key, cfg: ModelConfig, kind: str) -> Dict:
     raise ValueError(f"unknown block kind {kind}")
 
 
+def _moe_init(key, cfg: ModelConfig) -> Dict:
+    return moe_mod.moe_init(
+        key, cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.n_experts, _pdtype(cfg),
+        cfg.shared_expert, n_held=cfg.n_experts_held, shared_d_ff=cfg.shared_d_ff,
+        select_bias=cfg.score_func == "sigmoid",
+    )
+
+
+def _mla_kw(cfg: ModelConfig) -> Dict:
+    return dict(
+        n_heads=cfg.n_heads, qk_nope=cfg.qk_nope_head_dim,
+        qk_rope=cfg.qk_rope_head_dim, v_dim=cfg.v_head_dim,
+        rope_theta=cfg.rope_theta, eps=cfg.norm_eps, compute_dtype=_cdtype(cfg),
+    )
+
+
+def _expert_layer(p: Dict, cfg: ModelConfig, h: jnp.ndarray):
+    """The block's expert layer on normed h: (out, aux_loss, counters).
+    A layer told which experts it holds runs dropless and counts its
+    assignments; one that holds all runs the capacity dispatch."""
+    if cfg.n_experts_held:
+        out, counters = moe_mod.moe_held_apply(
+            p, h, n_experts=cfg.n_experts, k=cfg.experts_per_token,
+            first_held=cfg.first_expert_held, score_func=cfg.score_func,
+            scaling=cfg.routed_scaling,
+            compute_dtype=_cdtype(cfg),
+        )
+        return out, jnp.float32(0.0), counters
+    out, aux = moe_mod.moe_apply(
+        p, h, n_experts=cfg.n_experts, k=cfg.experts_per_token,
+        capacity_factor=cfg.moe_capacity_factor, compute_dtype=_cdtype(cfg),
+    )
+    return out, aux, {}
+
+
 def apply_block_train(
     p: Dict,
     kind: str,
@@ -110,11 +159,21 @@ def apply_block_train(
     positions: jnp.ndarray,
     impl: str,
     carry_state: Optional[Dict] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-sequence (train / prefill) block. Returns (x, aux_loss)."""
+) -> Tuple[jnp.ndarray, jnp.ndarray, Dict]:
+    """Full-sequence (train / prefill) block. Returns (x, aux_loss,
+    counters); only a held-expert layer has counters."""
     cd = _cdtype(cfg)
     aux = jnp.float32(0.0)
     eps = cfg.norm_eps
+    if kind in ("mla", "mla_moe"):
+        x = x + attn.mla_apply(
+            p["attn"], rmsnorm(p["ln1"], x, eps), positions, impl=impl, **_mla_kw(cfg)
+        )
+        h = rmsnorm(p["ln2"], x, eps)
+        if kind == "mla":
+            return x + swiglu(p["mlp"], h, cd), aux, {}
+        m, aux, counters = _expert_layer(p["moe"], cfg, h)
+        return x + m, aux, counters
     if kind in ("attention", "local_attention", "moe"):
         window = cfg.window if kind == "local_attention" else 0
         h = attn.attn_apply(
@@ -133,17 +192,10 @@ def apply_block_train(
         )
         x = x + h
         if kind == "moe":
-            m, aux = moe_mod.moe_apply(
-                p["moe"],
-                rmsnorm(p["ln2"], x, eps),
-                n_experts=cfg.n_experts,
-                k=cfg.experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor,
-                compute_dtype=cd,
-            )
-        else:
-            m = swiglu(p["mlp"], rmsnorm(p["ln2"], x, eps), cd)
-        return x + m, aux
+            m, aux, counters = _expert_layer(p["moe"], cfg, rmsnorm(p["ln2"], x, eps))
+            return x + m, aux, counters
+        m = swiglu(p["mlp"], rmsnorm(p["ln2"], x, eps), cd)
+        return x + m, aux, {}
     if kind == "rwkv":
         b, s, d = x.shape
         st = carry_state or rwkv_mod.rwkv_decode_states(b, d)
@@ -154,7 +206,7 @@ def apply_block_train(
         c, _ = rwkv_mod.rwkv_channel_mix(
             p["rwkv"]["channel"], rmsnorm(p["ln2"], x, eps), st["cm_prev"], cd
         )
-        return x + c, aux
+        return x + c, aux, {}
     if kind == "recurrent":
         b = x.shape[0]
         width = cfg.rnn_width or cfg.d_model
@@ -162,7 +214,7 @@ def apply_block_train(
         h, _ = rglru_mod.rglru_block_apply(p["rglru"], rmsnorm(p["ln1"], x, eps), st, cd, impl=impl)
         x = x + h
         m = swiglu(p["mlp"], rmsnorm(p["ln2"], x, eps), cd)
-        return x + m, aux
+        return x + m, aux, {}
     raise ValueError(kind)
 
 
@@ -192,7 +244,14 @@ def init_params(cfg: ModelConfig, key) -> Dict:
         params["head"] = embed_init(k_head, cfg.padded_vocab, cfg.d_model, dt)
     pattern = cfg.effective_pattern
     keys = jax.random.split(k_blocks, cfg.n_layers)
-    if cfg.is_homogeneous:
+    n_lead = cfg.first_dense_layers
+    if n_lead:
+        # leading dense layers as a list; the alike layers after them stacked
+        params["lead"] = [init_block(keys[i], cfg, pattern[i]) for i in range(n_lead)]
+        params["blocks"] = jax.vmap(lambda k: init_block(k, cfg, pattern[n_lead]))(
+            keys[n_lead:]
+        )
+    elif cfg.is_homogeneous:
         params["blocks"] = jax.vmap(lambda k: init_block(k, cfg, pattern[0]))(keys)
     else:
         period, n_periods, n_tail = _period_split(cfg)
@@ -215,6 +274,11 @@ def init_params(cfg: ModelConfig, key) -> Dict:
 
 def _layer_params(params: Dict, cfg: ModelConfig, i: int) -> Dict:
     """Per-layer param tree regardless of storage layout (used by decode)."""
+    if "lead" in params:
+        n_lead = len(params["lead"])
+        if i < n_lead:
+            return params["lead"][i]
+        return jax.tree_util.tree_map(lambda a: a[i - n_lead], params["blocks"])
     if "blocks" in params and cfg.is_homogeneous:
         return jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
     if "pblocks" in params:
@@ -235,24 +299,52 @@ def forward_hidden(
     remat: bool = True,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Embedded inputs (B,S,d) -> final hidden (B,S,d), total aux loss."""
+    h, aux, _ = forward_counted(params, cfg, x, positions, impl, remat)
+    return h, aux
+
+
+def forward_counted(
+    params: Dict,
+    cfg: ModelConfig,
+    x: jnp.ndarray,
+    positions: jnp.ndarray,
+    impl: str = "ref",
+    remat: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray, Dict]:
+    """:func:`forward_hidden` with the layers' counters, combined over
+    layers (``moe.merge_counters``); empty where no layer counts."""
     pattern = cfg.effective_pattern
+    counters: Dict = {}
+    if "lead" in params:
+        for blk, kind in zip(params["lead"], pattern):
+
+            def lead_layer(blk_, h, pos, kind=kind):
+                return apply_block_train(blk_, kind, cfg, h, pos, impl)[0]
+
+            fn = jax.checkpoint(lead_layer, prevent_cse=False) if remat else lead_layer
+            x = fn(blk, x, positions)
+        pattern = pattern[len(params["lead"]):]
     if cfg.is_homogeneous:
         kind = pattern[0]
 
         def body(carry, layer_params):
-            h, aux = carry
-            h2, a = apply_block_train(layer_params, kind, cfg, h, positions, impl)
-            return (h2, aux + a), None
+            h, aux, acc = carry
+            h2, a, c = apply_block_train(layer_params, kind, cfg, h, positions, impl)
+            return (h2, aux + a, moe_mod.merge_counters(acc, c)), None
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), params["blocks"])
+        if kind in ("moe", "mla_moe") and cfg.n_experts_held:
+            counters = moe_mod.zero_counters()
+        (x, aux, counters), _ = jax.lax.scan(
+            body, (x, jnp.float32(0.0), counters), params["blocks"]
+        )
     else:
         aux = jnp.float32(0.0)
         period, n_periods, n_tail = _period_split(cfg)
 
         def one_layer(blk_, h, pos, kind):
-            return apply_block_train(blk_, kind, cfg, h, pos, impl)
+            return apply_block_train(blk_, kind, cfg, h, pos, impl)[:2]
 
         if "pblocks" in params and n_periods:
 
@@ -280,7 +372,7 @@ def forward_hidden(
                 fn = jax.checkpoint(fn, prevent_cse=False)
             x, a = fn(blk, x, positions)
             aux = aux + a
-    return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux, counters
 
 
 def _embed_inputs(
@@ -309,15 +401,19 @@ def lm_loss(
     impl: str = "ref",
     remat: bool = True,
     loss_chunk: int = 512,
-) -> jnp.ndarray:
-    """Next-token cross-entropy (mean over valid tokens) + MoE aux."""
+    counted: bool = False,
+):
+    """Next-token cross-entropy (mean over valid tokens) + MoE aux; with
+    ``counted``, ``(loss, counters)`` (the layers' counters, combined over
+    layers)."""
     emb, positions, labels = _embed_inputs(params, cfg, batch)
-    h, aux = forward_hidden(params, cfg, emb, positions, impl, remat)
+    h, aux, counters = forward_counted(params, cfg, emb, positions, impl, remat)
     table = params["embed" if cfg.tie_embeddings else "head"]["table"]
     loss = chunked_softmax_xent(
         table, h, labels, cfg.vocab_size, chunk=loss_chunk, compute_dtype=_cdtype(cfg)
     )
-    return loss + cfg.router_aux_coef * aux
+    loss = loss + cfg.router_aux_coef * aux
+    return (loss, counters) if counted else loss
 
 
 # ---------------------------------------------------------------------------
@@ -331,7 +427,7 @@ def _decode_kinds(cfg: ModelConfig, max_seq: int, sliding_override: bool) -> Tup
     archs -- see DESIGN.md)."""
     out = []
     for kind in cfg.effective_pattern:
-        if kind in ("attention", "moe"):
+        if kind in ("attention", "moe", "mla", "mla_moe"):
             if sliding_override:
                 out.append((kind, min(cfg.window or 4096, max_seq)))
             else:
@@ -351,10 +447,16 @@ def init_decode_state(
     cache_dtype=jnp.bfloat16,
 ) -> Any:
     """Per-layer decode caches. Homogeneous stacks get layer-stacked caches
-    (scanned decode); patterned stacks get a list."""
+    (scanned decode); with leading dense layers ``{"lead": [...], "blocks":
+    stacked}``; patterned stacks get a list. Latent attention caches its
+    latent (:func:`attention.init_mla_cache`)."""
     kinds = _decode_kinds(cfg, max_seq, sliding_override)
 
     def one(kind: str, cache_len: int):
+        if kind in ("mla", "mla_moe"):
+            return attn.init_mla_cache(
+                batch, cache_len, cfg.kv_lora_rank, cfg.qk_rope_head_dim, cache_dtype
+            )
         if kind in ("attention", "moe", "local_attention"):
             return attn.init_kv_cache(batch, cache_len, cfg.n_kv_heads, cfg.head_dim, cache_dtype)
         if kind == "rwkv":
@@ -363,11 +465,16 @@ def init_decode_state(
             return rglru_mod.rglru_decode_state(batch, cfg.rnn_width or cfg.d_model, cfg.conv_width)
         raise ValueError(kind)
 
+    n_lead = cfg.first_dense_layers
     if cfg.is_homogeneous:
-        single = one(*kinds[0])
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_layers,) + a.shape).copy(), single
+        single = one(*kinds[n_lead])
+        stacked = jax.tree_util.tree_map(
+            lambda a: jnp.broadcast_to(a[None], (cfg.n_layers - n_lead,) + a.shape).copy(),
+            single,
         )
+        if n_lead:
+            return {"lead": [one(k, c) for k, c in kinds[:n_lead]], "blocks": stacked}
+        return stacked
     return [one(k, c) for k, c in kinds]
 
 
@@ -376,6 +483,15 @@ def apply_block_decode(
 ) -> Tuple[jnp.ndarray, Any]:
     cd = _cdtype(cfg)
     eps = cfg.norm_eps
+    if kind in ("mla", "mla_moe"):
+        h, state = attn.mla_decode(
+            p["attn"], rmsnorm(p["ln1"], x, eps), state, ring=ring, **_mla_kw(cfg)
+        )
+        x = x + h
+        m = rmsnorm(p["ln2"], x, eps)
+        if kind == "mla":
+            return x + swiglu(p["mlp"], m, cd), state
+        return x + _expert_layer(p["moe"], cfg, m)[0], state
     if kind in ("attention", "local_attention", "moe"):
         h, state = attn.attn_decode(
             p["attn"],
@@ -391,14 +507,7 @@ def apply_block_decode(
         )
         x = x + h
         if kind == "moe":
-            m, _ = moe_mod.moe_apply(
-                p["moe"],
-                rmsnorm(p["ln2"], x, eps),
-                n_experts=cfg.n_experts,
-                k=cfg.experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor,
-                compute_dtype=cd,
-            )
+            m = _expert_layer(p["moe"], cfg, rmsnorm(p["ln2"], x, eps))[0]
         else:
             m = swiglu(p["mlp"], rmsnorm(p["ln2"], x, eps), cd)
         return x + m, state
@@ -431,7 +540,14 @@ def decode_step(
     x = embed_lookup(params["embed"], tokens[:, None], cd)  # (B,1,d)
     pattern = cfg.effective_pattern
     if cfg.is_homogeneous:
-        kind = pattern[0]
+        lead = []
+        if "lead" in params:
+            for i, blk in enumerate(params["lead"]):
+                x, c = apply_block_decode(
+                    blk, pattern[i], cfg, x, caches["lead"][i], ring=sliding_override
+                )
+                lead.append(c)
+        kind = pattern[len(lead)]
 
         def body(h, xs):
             layer_params, layer_cache = xs
@@ -440,7 +556,9 @@ def decode_step(
             )
             return h2, new_cache
 
-        x, caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        body_caches = caches["blocks"] if lead else caches
+        x, body_caches = jax.lax.scan(body, x, (params["blocks"], body_caches))
+        caches = {"lead": lead, "blocks": body_caches} if lead else body_caches
     else:
         new_caches = []
         for i, kind in enumerate(pattern):
